@@ -93,6 +93,8 @@ def main(argv=None) -> None:
                     "(DRIM_VERIFY=0); BENCH_*.json records are then "
                     "stamped 'verified': false")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.no_verify:
         os.environ["DRIM_VERIFY"] = "0"
     if args.trace_out:

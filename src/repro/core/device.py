@@ -22,7 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+
 from jax.sharding import PartitionSpec as P
 
 from .faults import slot_ids_grid
@@ -247,9 +247,9 @@ def _sharded_program_runner(mesh):
         out = _device_run_program(DrimDevice(data=data, dcc=dcc), encoded)
         return out.data, out.dcc
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(spec, spec, P()), out_specs=(spec, spec),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(spec, spec, P()), out_specs=(spec, spec),
+                       check_vma=False)
     return jax.jit(fn)
 
 

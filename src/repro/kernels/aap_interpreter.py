@@ -2,187 +2,178 @@
 
 The lax engines ("resident"/"queued") specialize the AAP stream at trace
 time — `isa.run_program_unrolled` unrolls every instruction into the XLA
-graph with static word-line addresses.  This kernel is the opposite
-design point, and the closest software analogue of the DRIM sub-array
-itself: the program is lowered host-side to the int32 micro-op table of
+graph with static word-lines.  This kernel is the opposite design point,
+and the closest software analogue of the DRIM sub-array itself: the
+program is lowered host-side to the int32 micro-op table of
 `isa.encode_kernel_stream` and executed on-device by a real program
-counter (`lax.fori_loop` + `lax.switch` over the three sense-amp
-outcomes: pass-through, DRA-XNOR, TRA-MAJ3).  The row-plane block stays
-resident in VMEM across the whole program — rows never round-trip
-through HBM between AAPs, exactly as DRAM rows never leave the sub-array
-between ACTIVATEs.
+counter (`lax.fori_loop` over the stream, the three sense-amp outcomes
+— pass-through, DRA-XNOR, TRA-MAJ3 — selected by all-ones masks).  The
+row-plane state stays resident in a VMEM scratch across the whole
+program — rows never round-trip through HBM between AAPs, exactly as
+DRAM rows never leave the sub-array between ACTIVATEs.
 
-Grid layout: bulk bit-wise ops make every packed word column
-independent, so one wave's [n_rows_in, chips, banks, subarrays,
-row_words] tile block flattens to [n_rows_in, total_words] and the 1-D
-grid tiles the word axis in `block_cols` chunks — `block_cols ==
-row_words` degenerates to literally one grid cell per sub-array slot;
-the default groups slots so a cell fills the VPU lanes.  Each cell owns
-a fresh zeroed state block of `dcc_state_rows(n_rows)` rows (normal rows
-plus the two DCC cells) and replays the stream over it.
+Memory layout (what the TPU's Mosaic compiler accepts):
 
-On non-TPU backends the kernel runs under `interpret=True` (the
-functional escape hatch CPU CI uses); set REPRO_PALLAS_INTERPRET=0/1 to
-force either mode.
+  * the micro-op table (and, with faults, the per-op thresholds) rides
+    in SMEM through scalar prefetch, so every word-line index is a
+    scalar and a row is addressed as `state[pl.ds(row, 1)]` on the
+    untiled leading axis of the state scratch;
+  * word columns are independent, so one wave's [n_rows_in, chips,
+    banks, subarrays, row_words] tile block flattens to
+    [n_rows_in, total_words] and folds to [n_rows_in, total/128, 128]:
+    each grid cell owns `block_cols` words as a full (sublane, lane)
+    tile slab per row;
+  * planes are bit-cast to int32 at the kernel boundary — the ops are
+    pure bit-wise, so signedness never shows, and a scalar BL̄ flag `f`
+    becomes the XOR mask `-f` (0 or all-ones).
+
+Each cell starts from a fresh zeroed state of `dcc_state_rows(n_rows)`
+rows (normal rows plus the two DCC cells), loads its operand planes
+into rows [0, n_in) and replays the stream over it.
+
+The kernel runs compiled on a TPU and in interpret mode on every other
+backend; `interpret=` overrides that choice per call.
 """
 from __future__ import annotations
 
 import functools
-import os
-from typing import Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.faults import fault_mask, mix32, slot_ids_grid
 from repro.core.isa import (AAP, KSTREAM_COLS, OP_DRA, OP_TRA,
                             dcc_state_rows, encode_kernel_stream,
                             kstream_slot)
 
-# Word columns per grid cell: 4096 lane-words x ~32 state rows is
-# ~0.5 MiB of VMEM, far under budget, and a multiple of the 128-lane VPU.
+# Largest word-column block per grid cell, and the VMEM the cell's
+# buffers may take (state scratch + double-buffered in/out blocks).
+# Blocks shrink in steps of one (8, 128) tile slab until they fit.
 BLOCK_COLS = 4096
 _LANES = 128
+_SLAB = 8 * _LANES
+_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def default_interpret() -> bool:
-    """interpret=True everywhere but real TPU; REPRO_PALLAS_INTERPRET
-    (0/1/auto) overrides."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
-    if env not in ("", "auto"):
-        return env not in ("0", "false", "no")
+    """Compiled on a TPU, interpret mode on every other backend."""
     return jax.default_backend() != "tpu"
 
 
-def _negmask(flag: jax.Array) -> jax.Array:
-    """All-ones when `flag` says the access rides the complemented BL̄."""
-    return jnp.where(flag != 0, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+def block_cols_for(n_in: int, n_state: int, n_out: int, n_meta: int,
+                   total: int, block_cols: int = BLOCK_COLS) -> int:
+    """Words per grid cell: a multiple of one (8, 128) slab, no larger
+    than the padded payload, shrunk until the cell's VMEM fits."""
+    bc = min(block_cols, -(-total // _SLAB) * _SLAB)
+    bc = max(_SLAB, bc // _SLAB * _SLAB)
+    per_word = 4 * (n_state + 2 * (n_in + n_out + n_meta))
+    while bc > _SLAB and bc * per_word > _VMEM_BUDGET:
+        bc -= _SLAB
+    return bc
 
 
-def _interp_kernel(n_in: int, n_state: int,
+def _interp_kernel(n_in: int, n_ins: int,
                    out_slots: Tuple[Tuple[int, int], ...],
-                   stream_ref, in_ref, out_ref):
+                   fault_cfg: Optional[Tuple[int, Tuple]], *refs):
     """One grid cell: replay the whole micro-op stream over its columns.
 
-    State rows [0, n_in) hold the staged operand planes; the rest starts
-    as a fresh (zeroed) sub-array.  Reads resolve before writes within
-    one AAP, and the up-to-four write slots replay in instruction-arg
-    order — bit-exact with `run_program_unrolled`.
+    refs: stream (SMEM, flat [n_ins * KSTREAM_COLS]), then with faults
+    the per-op thresholds (SMEM [n_ins]) and the [2, S, 128] column
+    metadata block, then the [n_in, S, 128] operand block, the
+    [n_out, S, 128] output block and the [n_state, S, 128] state
+    scratch.  Reads resolve before writes within one AAP, and the
+    up-to-four write slots replay in instruction-arg order — bit-exact
+    with `run_program_unrolled`.
+
+    fault_cfg = (n_positions, stuck) arms Table-3 injection: each
+    DRA/TRA XORs the counter-based flip mask the lax engines draw for
+    its (op-index, slot) onto the BL value before write-back (the
+    threshold is zero for copies and protected ops), and `stuck`
+    pins stuck-at state rows after every instruction.
     """
-    block = in_ref.shape[1]
-    stream = stream_ref[...]
-    state = jnp.zeros((n_state, block), jnp.uint32)
-    state = jax.lax.dynamic_update_slice(state, in_ref[...], (0, 0))
+    if fault_cfg is None:
+        stream_ref, in_ref, out_ref, st_ref = refs
+    else:
+        stream_ref, thresh_ref, meta_ref, in_ref, out_ref, st_ref = refs
+        n_positions, stuck = fault_cfg
+        slot_h = jax.lax.bitcast_convert_type(meta_ref[0], jnp.uint32)
+        word_ids = jax.lax.bitcast_convert_type(meta_ref[1], jnp.uint32)
 
-    def step(i, st):
-        ins = jax.lax.dynamic_slice(stream, (i, 0), (1, KSTREAM_COLS))[0]
+    tile = st_ref.shape[1:]
 
-        def rd(k):
-            row = jax.lax.dynamic_slice(st, (ins[1 + 2 * k], 0),
-                                        (1, block))[0]
-            return row ^ _negmask(ins[2 + 2 * k])
-
-        r0, r1, r2 = rd(0), rd(1), rd(2)
-        bl = jax.lax.switch(ins[0], (
-            lambda a, b, c: a,                            # COPY/COPY2
-            lambda a, b, c: ~(a ^ b),                     # DRA: BL = XNOR
-            lambda a, b, c: (a & b) | (a & c) | (b & c),  # TRA: MAJ3
-        ), r0, r1, r2)
-        for k in range(4):                     # write slots, in arg order
-            row, neg, en = ins[7 + 3 * k], ins[8 + 3 * k], ins[9 + 3 * k]
-            cur = jax.lax.dynamic_slice(st, (row, 0), (1, block))
-            val = jnp.where(en != 0, (bl ^ _negmask(neg))[None, :], cur)
-            st = jax.lax.dynamic_update_slice(st, val, (row, 0))
-        return st
-
-    if stream.shape[0]:
-        state = jax.lax.fori_loop(0, stream.shape[0], step, state)
-    out_ref[...] = jnp.stack(
-        [~state[row] if neg else state[row] for row, neg in out_slots])
-
-
-def _interp_kernel_faulted(n_in: int, n_state: int,
-                           out_slots: Tuple[Tuple[int, int], ...],
-                           n_positions: int,
-                           stuck: Tuple[Tuple[int, int], ...],
-                           stream_ref, meta_ref, thresh_ref,
-                           in_ref, out_ref):
-    """Fault-injecting twin of `_interp_kernel`.
-
-    Two extra inputs carry the fault state as data: `meta_ref` is
-    [2, block] uint32 — per-column `mix32(global_slot ^ seed)` and
-    per-column word index — and `thresh_ref` is the per-instruction
-    failure threshold ([n_ins, 1] uint32, zero for copies and protected
-    ops).  Each DRA/TRA draws the same counter-based flip mask the lax
-    engines draw for its (op-index, slot) and XORs it onto the BL value
-    before the write-back replay; `stuck` pins stuck-at state rows
-    after every instruction.  A separate kernel so the fault-free build
-    stays byte-identical to `_interp_kernel`.
-    """
-    block = in_ref.shape[1]
-    stream = stream_ref[...]
-    thresh = thresh_ref[...]
-    meta = meta_ref[...]
-    slot_h, word_ids = meta[0], meta[1]
-
-    def force(st):
+    def force():
         for row, v in stuck:
-            const = jnp.full((1, block),
-                             0xFFFFFFFF if v else 0, jnp.uint32)
-            st = jax.lax.dynamic_update_slice(st, const, (row, 0))
-        return st
+            st_ref[pl.ds(row, 1)] = jnp.full((1,) + tile, -v, jnp.int32)
 
-    state = jnp.zeros((n_state, block), jnp.uint32)
-    state = jax.lax.dynamic_update_slice(state, in_ref[...], (0, 0))
-    state = force(state)
+    st_ref[...] = jnp.zeros(st_ref.shape, jnp.int32)
+    st_ref[pl.ds(0, n_in)] = in_ref[...]
+    if fault_cfg is not None:
+        force()
 
-    def step(i, st):
-        ins = jax.lax.dynamic_slice(stream, (i, 0), (1, KSTREAM_COLS))[0]
+    def step(i, carry):
+        base = i * KSTREAM_COLS
 
         def rd(k):
-            row = jax.lax.dynamic_slice(st, (ins[1 + 2 * k], 0),
-                                        (1, block))[0]
-            return row ^ _negmask(ins[2 + 2 * k])
+            row = st_ref[pl.ds(stream_ref[base + 1 + 2 * k], 1)]
+            return row ^ -stream_ref[base + 2 + 2 * k]
 
-        r0, r1, r2 = rd(0), rd(1), rd(2)
-        bl = jax.lax.switch(ins[0], (
-            lambda a, b, c: a,                            # COPY/COPY2
-            lambda a, b, c: ~(a ^ b),                     # DRA: BL = XNOR
-            lambda a, b, c: (a & b) | (a & c) | (b & c),  # TRA: MAJ3
-        ), r0, r1, r2)
-        t = jax.lax.dynamic_slice(thresh, (i, 0), (1, 1))[0, 0]
-        bl = bl ^ fault_mask(t, i, slot_h, word_ids, n_positions)
+        a, b, c = rd(0), rd(1), rd(2)
+        kind = stream_ref[base]
+        is_dra = -(kind == 1).astype(jnp.int32)
+        is_tra = -(kind == 2).astype(jnp.int32)
+        bl = ((a & ~(is_dra | is_tra))                   # COPY/COPY2
+              | (~(a ^ b) & is_dra)                      # DRA: XNOR
+              | (((a & b) | (a & c) | (b & c)) & is_tra))  # TRA: MAJ3
+        if fault_cfg is not None:
+            # same-width int convert keeps the bits (XLA semantics)
+            t = thresh_ref[i].astype(jnp.uint32)
+            flip = fault_mask(t, i, slot_h, word_ids, n_positions)
+            bl = bl ^ jax.lax.bitcast_convert_type(flip, jnp.int32)[None]
         for k in range(4):                     # write slots, in arg order
-            row, neg, en = ins[7 + 3 * k], ins[8 + 3 * k], ins[9 + 3 * k]
-            cur = jax.lax.dynamic_slice(st, (row, 0), (1, block))
-            val = jnp.where(en != 0, (bl ^ _negmask(neg))[None, :], cur)
-            st = jax.lax.dynamic_update_slice(st, val, (row, 0))
-        return force(st)
+            col = base + 7 + 3 * k
+            row, neg = stream_ref[col], stream_ref[col + 1]
 
-    if stream.shape[0]:
-        state = jax.lax.fori_loop(0, stream.shape[0], step, state)
-    out_ref[...] = jnp.stack(
-        [~state[row] if neg else state[row] for row, neg in out_slots])
+            @pl.when(stream_ref[col + 2] != 0)
+            def _write():
+                st_ref[pl.ds(row, 1)] = bl ^ -neg
+        if fault_cfg is not None:
+            force()
+        return carry
+
+    jax.lax.fori_loop(0, n_ins, step, 0)
+    for j, (row, neg) in enumerate(out_slots):
+        v = st_ref[pl.ds(row, 1)]
+        out_ref[pl.ds(j, 1)] = ~v if neg else v
 
 
 def _op_thresholds(program: Tuple[AAP, ...], faults) -> np.ndarray:
-    """[n_ins, 1] uint32 per-instruction failure thresholds."""
-    tvec = np.zeros((len(program), 1), np.uint32)
+    """[n_ins] per-instruction failure thresholds, uint32 bits held as
+    int32 (SMEM is a 32-bit scalar memory)."""
+    tvec = np.zeros(len(program), np.uint32)
     prot = set(faults.protected_ops)
     for i, ins in enumerate(program):
         if i in prot:
             continue
         if ins.op == OP_DRA:
-            tvec[i, 0] = faults.dra_thresh
+            tvec[i] = faults.dra_thresh
         elif ins.op == OP_TRA:
-            tvec[i, 0] = faults.tra_thresh
-    return tvec
+            tvec[i] = faults.tra_thresh
+    return tvec.view(np.int32)
 
 
 def _round_up(n: int, mult: int) -> int:
     return -(-n // mult) * mult
+
+
+def _fold(x: jax.Array, padded: int) -> jax.Array:
+    """[rows, total] planes -> [rows, padded/128, 128] int32 slabs."""
+    x = jax.lax.bitcast_convert_type(x.astype(jnp.uint32), jnp.int32)
+    x = jnp.pad(x, ((0, 0), (0, padded - x.shape[1])))
+    return x.reshape(x.shape[0], padded // _LANES, _LANES)
 
 
 def pallas_wave_fn(program: Tuple[AAP, ...], result_rows: Tuple[int, ...],
@@ -198,12 +189,11 @@ def pallas_wave_fn(program: Tuple[AAP, ...], result_rows: Tuple[int, ...],
     `_wave_runner` memoizes the compiled executor.
 
     With a `FaultModel`, per-column slot hashes and per-op thresholds
-    ride into the kernel as extra inputs and the fault-injecting kernel
-    twin replays the stream — drawing the exact flips the lax engines
-    draw for the same (seed, op-index, global slot).  `bank_geom` =
-    (bank_lo, banks_total) anchors per-queue payloads at their physical
-    bank offset.  Padded grid columns may draw (discarded) flips; they
-    are sliced away with the padding.
+    ride into the kernel as extra inputs and the kernel draws the exact
+    flips the lax engines draw for the same (seed, op-index, global
+    slot).  `bank_geom` = (bank_lo, banks_total) anchors per-queue
+    payloads at their physical bank offset.  Padded grid columns may
+    draw (discarded) flips; they are sliced away with the padding.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -230,33 +220,33 @@ def pallas_wave_fn(program: Tuple[AAP, ...], result_rows: Tuple[int, ...],
             return jnp.stack([pick(row, neg) for row, neg in out_slots])
         return one_wave
 
-    stream = jnp.asarray(encode_kernel_stream(program, n_rows=n_rows))
-    n_ins = stream.shape[0]
+    stream = jnp.asarray(
+        encode_kernel_stream(program, n_rows=n_rows).reshape(-1))
+    n_ins = len(program)
     n_state = dcc_state_rows(n_rows)
     n_out = len(result_rows)
-    thresh = (jnp.asarray(_op_thresholds(program, faults))
-              if faults is not None else None)
+    prefetch = (stream,)
+    if faults is not None:
+        prefetch += (jnp.asarray(_op_thresholds(program, faults)),)
 
     def one_wave(tiles: jax.Array) -> jax.Array:
         n_in = tiles.shape[0]
-        flat = tiles.astype(jnp.uint32).reshape(n_in, -1)
+        flat = tiles.reshape(n_in, -1)
         total = flat.shape[1]
-        bc = min(block_cols, _round_up(total, _LANES))
+        n_meta = 2 if faults is not None else 0
+        bc = block_cols_for(n_in, n_state, n_out, n_meta, total,
+                            block_cols)
         padded = _round_up(total, bc)
-        flat = jnp.pad(flat, ((0, 0), (0, padded - total)))
-        stream_spec = pl.BlockSpec((n_ins, KSTREAM_COLS), lambda j: (0, 0))
-        in_spec = pl.BlockSpec((n_in, bc), lambda j: (0, j))
-        out_spec = pl.BlockSpec((n_out, bc), lambda j: (0, j))
-        if faults is None:
-            out = pl.pallas_call(
-                functools.partial(_interp_kernel, n_in, n_state, out_slots),
-                grid=(padded // bc,),
-                in_specs=[stream_spec, in_spec],
-                out_specs=out_spec,
-                out_shape=jax.ShapeDtypeStruct((n_out, padded), jnp.uint32),
-                interpret=interpret,
-            )(stream, flat)
-        else:
+        sub = bc // _LANES
+
+        def spec(rows):
+            return pl.BlockSpec((rows, sub, _LANES),
+                                lambda j, *_: (0, j, 0))
+
+        inputs = (_fold(flat, padded),)
+        in_specs = [spec(n_in)]
+        cfg = None
+        if faults is not None:
             c, b, s, w = tiles.shape[1:]
             grid = slot_ids_grid(c, b, s, bank_lo=bank_lo,
                                  banks_total=banks_total)
@@ -264,18 +254,25 @@ def pallas_wave_fn(program: Tuple[AAP, ...], result_rows: Tuple[int, ...],
             meta = jnp.stack([jnp.repeat(slot_h, w),
                               jnp.tile(jnp.arange(w, dtype=jnp.uint32),
                                        grid.size)])
-            meta = jnp.pad(meta, ((0, 0), (0, padded - total)))
-            out = pl.pallas_call(
-                functools.partial(_interp_kernel_faulted, n_in, n_state,
-                                  out_slots, w * 32, stuck),
+            inputs = (_fold(meta, padded),) + inputs
+            in_specs = [spec(2)] + in_specs
+            cfg = (w * 32, stuck)
+        out = pl.pallas_call(
+            functools.partial(_interp_kernel, n_in, n_ins, out_slots, cfg),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch),
                 grid=(padded // bc,),
-                in_specs=[stream_spec,
-                          pl.BlockSpec((2, bc), lambda j: (0, j)),
-                          pl.BlockSpec((n_ins, 1), lambda j: (0, 0)),
-                          in_spec],
-                out_specs=out_spec,
-                out_shape=jax.ShapeDtypeStruct((n_out, padded), jnp.uint32),
-                interpret=interpret,
-            )(stream, meta, thresh, flat)
-        return out[:, :total].reshape((n_out,) + tiles.shape[1:])
+                in_specs=in_specs,
+                out_specs=spec(n_out),
+                scratch_shapes=[pltpu.VMEM((n_state, sub, _LANES),
+                                           jnp.int32)]),
+            out_shape=jax.ShapeDtypeStruct((n_out, padded // _LANES,
+                                            _LANES), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            interpret=interpret,
+        )(*prefetch, *inputs)
+        out = jax.lax.bitcast_convert_type(out, jnp.uint32)
+        return out.reshape(n_out, padded)[:, :total].reshape(
+            (n_out,) + tiles.shape[1:])
     return one_wave

@@ -21,7 +21,8 @@ Backward: recompute-based (flash-attn v2 style), two passes:
     the GQA rep group (n_rep folded into the Sq sweep via index maps).
   * dq pass: grid (B, H, Sq/BQ, Sk/BK) accumulates dq over Sk.
 Both recompute p = exp(qk - lse) from the saved per-row LSE, so nothing
-[S, S]-shaped ever touches HBM.
+[S, S]-shaped ever touches HBM.  The LSE and delta rows travel
+lane-replicated as [B, H, Sq, 128] (see `LANES`).
 
 `flash_attention(..., interpret=True)` runs the kernel body in Python on
 CPU — that is how tests/test_flash_attention.py sweeps shapes against
@@ -39,6 +40,10 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 NEG_INF = -1e30
+# Per-row softmax statistics (running max, running sum, LSE, delta) are
+# kept lane-replicated as [rows, LANES]: a block whose trailing dims are
+# (bq, 128) is one the TPU tiling accepts, where a bare [bq] row is not.
+LANES = 128
 
 
 # --------------------------------------------------------------------------
@@ -67,19 +72,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         kj = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(qi >= kj, s, NEG_INF)
 
-    m_prev = m_ref[...]                             # [BQ]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                             # [BQ, LANES]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new[:, :1])
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(kb == (sk // bk) - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
@@ -104,16 +109,17 @@ def _flash_fwd(q, k, v, *, causal, n_rep, bq, bk, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, i, j: (b_, h_, i)),
+            pl.BlockSpec((1, 1, bq, LANES),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sq, LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),   # acc
-            pltpu.VMEM((bq,), jnp.float32),     # m (running max)
-            pltpu.VMEM((bq,), jnp.float32),     # l (running sum)
+            pltpu.VMEM((bq, d), jnp.float32),       # acc
+            pltpu.VMEM((bq, LANES), jnp.float32),   # m (running max)
+            pltpu.VMEM((bq, LANES), jnp.float32),   # l (running sum)
         ],
         interpret=interpret,
     )(q, k, v)
@@ -140,8 +146,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)             # [BK, D]
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)           # [BQ, D]
-    lse = lse_ref[0, 0]                             # [BQ]
-    delta = delta_ref[0, 0]                         # [BQ]
+    lse = lse_ref[0, 0][:, :1]                      # [BQ, 1]
+    delta = delta_ref[0, 0][:, :1]                  # [BQ, 1]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -150,13 +156,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         kj = pl.program_id(2) * bk + jax.lax.broadcasted_iota(
             jnp.int32, (bq, bk), 1)
         s = jnp.where(qi >= kj, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                   # [BQ, BK]
+    p = jnp.exp(s - lse)                            # [BQ, BK]
 
     dv_acc[...] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dk_acc[...] += jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -178,8 +184,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    lse = lse_ref[0, 0][:, :1]
+    delta = delta_ref[0, 0][:, :1]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -188,10 +194,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             jnp.int32, (bq, bk), 0)
         kj = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(qi >= kj, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dq_acc[...] += jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -206,8 +212,9 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, n_rep, bq, bk, interpret):
     b, h, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     scale = 1.0 / (d ** 0.5)
-    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
-                    axis=-1)                              # [B, H, Sq]
+    delta = jnp.broadcast_to(
+        jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
+                axis=-1, keepdims=True), lse.shape)       # [B, H, Sq, LANES]
 
     # dk/dv: one kv-head per grid row; sweep (rep, Sq-blocks) innermost.
     grid_kv = (b, hkv, sk // bk, n_rep * (sq // bq))
@@ -217,7 +224,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, n_rep, bq, bk, interpret):
         return (b_, h_ * n_rep + i // nqb, i % nqb, 0)
 
     def lmap(b_, h_, j, i):
-        return (b_, h_ * n_rep + i // nqb, i % nqb)
+        return (b_, h_ * n_rep + i // nqb, i % nqb, 0)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -228,8 +235,8 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, n_rep, bq, bk, interpret):
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, bq, d), qmap),                      # do
-            pl.BlockSpec((1, 1, bq), lmap),                         # lse
-            pl.BlockSpec((1, 1, bq), lmap),                         # delta
+            pl.BlockSpec((1, 1, bq, LANES), lmap),                  # lse
+            pl.BlockSpec((1, 1, bq, LANES), lmap),                  # delta
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
@@ -257,8 +264,10 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, n_rep, bq, bk, interpret):
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h_, i, j: (b_, h_ // n_rep, j, 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, i, j: (b_, h_, i)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, i, j: (b_, h_, i)),
+            pl.BlockSpec((1, 1, bq, LANES),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, bq, LANES),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),

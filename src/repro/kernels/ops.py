@@ -1,18 +1,15 @@
 """Public jit'd wrappers for the DRIM-X kernels with backend dispatch.
 
-Pallas targets TPU (Mosaic); on the CPU build/CI (and in the AOT dry-run,
-which lowers for the host platform) every op falls back to its pure-jnp
-reference — numerically identical by the kernel test suite.  Set
-REPRO_FORCE_PALLAS=interpret to exercise the Pallas path on CPU.
+The backend alone decides what runs:
 
-Dispatch matrix:
-    backend==tpu                  -> pallas_call (compiled, Mosaic)
-    REPRO_FORCE_PALLAS=interpret  -> pallas_call (interpret mode)
-    otherwise                     -> ref.py jnp oracle (XLA-fused)
+    backend == tpu   -> pallas_call, compiled by Mosaic
+    otherwise        -> ref.py jnp oracle (XLA-fused)
+
+The two are numerically identical: the kernel test suite runs every
+Pallas kernel in interpret mode against the same oracles, and
+`tests/test_tpu_compile.py` compiles the main-path kernels for a v5e.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -27,22 +24,16 @@ from .xnor_popcount import xnor_gemm_packed as _xnor_gemm_pallas
 WORD_BITS = 32
 
 
-def _mode() -> str:
-    force = os.environ.get("REPRO_FORCE_PALLAS", "")
-    if force == "interpret":
-        return "interpret"
-    if force == "off":
-        return "ref"
-    return "tpu" if jax.default_backend() == "tpu" else "ref"
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 # --- bulk bit-wise ops -------------------------------------------------------
 
 def bitwise(op: str, a, b=None, c=None):
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return ref.bitwise_ref(op, a, b, c)
-    return _bitwise_pallas(op, a, b, c, interpret=(m == "interpret"))
+    return _bitwise_pallas(op, a, b, c)
 
 
 def xnor(a, b):
@@ -63,25 +54,22 @@ def pack_signs(x):
     """[..., K] -> [..., ceil(K/32)] uint32 sign words (flattens leading)."""
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    m = _mode()
-    if m == "ref":
-        k = x2.shape[-1]
-        pad = (-k) % WORD_BITS
+    if _on_tpu():
+        out = _pack_pallas(x2)
+    else:
+        pad = (-x2.shape[-1]) % WORD_BITS
         x2 = jnp.pad(x2, ((0, 0), (0, pad)), constant_values=-1.0)
         out = ref.pack_signs_ref(x2)
-    else:
-        out = _pack_pallas(x2, interpret=(m == "interpret"))
     return out.reshape(*lead, out.shape[-1])
 
 
 def unpack_signs(p, dtype=jnp.bfloat16):
     lead = p.shape[:-1]
     p2 = p.reshape(-1, p.shape[-1])
-    m = _mode()
-    if m == "ref":
-        out = ref.unpack_signs_ref(p2, dtype)
+    if _on_tpu():
+        out = _unpack_pallas(p2, dtype)
     else:
-        out = _unpack_pallas(p2, dtype, interpret=(m == "interpret"))
+        out = ref.unpack_signs_ref(p2, dtype)
     return out.reshape(*lead, out.shape[-1])
 
 
@@ -110,11 +98,9 @@ def unpack_sign_bits_np(packed, k_bits: int):
 
 def xnor_gemm_packed(a_packed, b_packed, k_bits: int):
     """C[M,N] int32 = ±1-dot of packed sign rows (XNOR-popcount identity)."""
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return ref.xnor_gemm_ref(a_packed, b_packed, k_bits)
-    return _xnor_gemm_pallas(a_packed, b_packed, k_bits,
-                             interpret=(m == "interpret"))
+    return _xnor_gemm_pallas(a_packed, b_packed, k_bits)
 
 
 def binary_matmul(x, w_packed, k_bits: int, dtype=jnp.bfloat16):
@@ -132,11 +118,9 @@ def binary_matmul(x, w_packed, k_bits: int, dtype=jnp.bfloat16):
 # --- bit-plane adder ---------------------------------------------------------
 
 def bitplane_add(a_planes, b_planes):
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return ref.bitplane_add_ref(a_planes, b_planes)
-    return _bitplane_add_pallas(a_planes, b_planes,
-                                interpret=(m == "interpret"))
+    return _bitplane_add_pallas(a_planes, b_planes)
 
 
 # --- popcount (VPU path, used by hamming-distance style apps) ---------------

@@ -5,10 +5,10 @@ unpack_signs: [R, W] uint32 -> [R, W*32] ±1 dtype
 
 These are bandwidth-bound layout ops (the DRIM "RowClone" analogue: data
 enters the compute-capable layout once, then all bulk ops run on packed
-rows).  The pack kernel processes one 128-lane stripe of 4 output words
-per grid step; both kernels are validated against ref.py oracles in
-interpret mode and exposed through ops.py with a fused jnp fallback for
-non-TPU backends.
+rows).  Each grid step handles up to 128 packed words (4096 dense
+columns); both kernels are validated against ref.py oracles in interpret
+mode and exposed through ops.py with a fused jnp fallback for non-TPU
+backends.
 """
 from __future__ import annotations
 
@@ -19,20 +19,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 WORD_BITS = 32
-BR = 256          # rows per block
-BWORDS = 32       # packed words per block -> 1024 input columns
+BR = 256          # rows per block (fewer when the array has fewer)
+BWORDS = 128      # packed words per block -> 4096 unpacked columns
 
 
-def _pack_kernel(x_ref, o_ref):
-    x = x_ref[...]                       # [BR, BWORDS*32]
-    bits = (x >= 0).astype(jnp.uint32)
-    b3 = bits.reshape(x.shape[0], BWORDS, WORD_BITS)
-    w = jnp.uint32(1) << jnp.arange(WORD_BITS, dtype=jnp.uint32)
-    o_ref[...] = (b3 * w[None, None, :]).sum(-1).astype(jnp.uint32)
+def _blocks(r: int, w: int):
+    """(rows, words) per block and the padded (rows, words): the whole
+    packed width up to BWORDS words, else BWORDS-word (lane-dense)
+    blocks — the two last-dim shapes the TPU tiling accepts."""
+    br = min(BR, pl.cdiv(r, 8) * 8)
+    bw = w if w <= BWORDS else BWORDS
+    return br, bw, pl.cdiv(r, br) * br, pl.cdiv(w, bw) * bw
+
+
+def _pack_kernel(x_ref, lo_ref, hi_ref, o_ref):
+    """Pack by matmul: bit k of the block lands in word k // 32 through
+    a 0/1 x power-of-two product, 16 bits per half so every f32 sum is
+    exact.  (Mosaic neither splits the lane axis into (words, 32) nor
+    reduces unsigned ints, so the VPU shift-and-sum form does not
+    compile.)"""
+    bits = (x_ref[...] >= 0).astype(jnp.bfloat16)          # [br, Kb]
+    lo = jnp.dot(bits, lo_ref[...], preferred_element_type=jnp.float32)
+    hi = jnp.dot(bits, hi_ref[...], preferred_element_type=jnp.float32)
+    word = lo.astype(jnp.int32) | (hi.astype(jnp.int32) << 16)
+    o_ref[...] = jax.lax.bitcast_convert_type(word, jnp.uint32)
+
+
+def _pack_weights(k_block: int):
+    """[Kb, Kb/32] bf16 place values of the low and high 16 bits."""
+    k = jnp.arange(k_block)
+    bit = k % WORD_BITS
+    onto = (k // WORD_BITS)[:, None] == jnp.arange(k_block // WORD_BITS)
+    place = jnp.exp2((bit % 16).astype(jnp.float32))[:, None]
+    lo = jnp.where(onto & (bit < 16)[:, None], place, 0.0)
+    hi = jnp.where(onto & (bit >= 16)[:, None], place, 0.0)
+    return lo.astype(jnp.bfloat16), hi.astype(jnp.bfloat16)
 
 
 def _unpack_kernel(p_ref, o_ref, *, dtype):
-    p = p_ref[...]                       # [BR, BWORDS]
+    p = p_ref[...]                       # [br, bw]
     shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)
     bits = (p[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
     pm1 = (bits.astype(jnp.int32) * 2 - 1).astype(dtype)
@@ -44,20 +69,21 @@ def pack_signs(x: jax.Array, *, interpret: bool = False) -> jax.Array:
     """[R, K] -> [R, ceil(K/32)] uint32 sign-bit words (pad bits = 0)."""
     r, k = x.shape
     w = pl.cdiv(k, WORD_BITS)
-    kp = pl.cdiv(w, BWORDS) * BWORDS * WORD_BITS
-    rp = pl.cdiv(r, BR) * BR
+    br, bw, rp, wp = _blocks(r, w)
     # pad with -1 so pad bits pack to 0
-    x2 = jnp.pad(x.astype(jnp.float32), ((0, rp - r), (0, kp - k)),
+    x2 = jnp.pad(x.astype(jnp.float32),
+                 ((0, rp - r), (0, wp * WORD_BITS - k)),
                  constant_values=-1.0)
-    grid = (rp // BR, kp // (BWORDS * WORD_BITS))
+    lo, hi = _pack_weights(bw * WORD_BITS)
+    wspec = pl.BlockSpec((bw * WORD_BITS, bw), lambda i, j: (0, 0))
     out = pl.pallas_call(
-        _pack_kernel, grid=grid,
-        in_specs=[pl.BlockSpec((BR, BWORDS * WORD_BITS),
-                               lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((BR, BWORDS), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rp, kp // WORD_BITS), jnp.uint32),
+        _pack_kernel, grid=(rp // br, wp // bw),
+        in_specs=[pl.BlockSpec((br, bw * WORD_BITS), lambda i, j: (i, j)),
+                  wspec, wspec],
+        out_specs=pl.BlockSpec((br, bw), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((rp, wp), jnp.uint32),
         interpret=interpret,
-    )(x2)
+    )(x2, lo, hi)
     return out[:r, :w]
 
 
@@ -66,15 +92,13 @@ def unpack_signs(p: jax.Array, dtype=jnp.bfloat16, *,
                  interpret: bool = False) -> jax.Array:
     """[R, W] uint32 -> [R, W*32] ±1 values of `dtype`."""
     r, w = p.shape
-    rp = pl.cdiv(r, BR) * BR
-    wp = pl.cdiv(w, BWORDS) * BWORDS
+    br, bw, rp, wp = _blocks(r, w)
     p2 = jnp.pad(p.astype(jnp.uint32), ((0, rp - r), (0, wp - w)))
-    grid = (rp // BR, wp // BWORDS)
     out = pl.pallas_call(
-        functools.partial(_unpack_kernel, dtype=dtype), grid=grid,
-        in_specs=[pl.BlockSpec((BR, BWORDS), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((BR, BWORDS * WORD_BITS),
-                               lambda i, j: (i, j)),
+        functools.partial(_unpack_kernel, dtype=dtype),
+        grid=(rp // br, wp // bw),
+        in_specs=[pl.BlockSpec((br, bw), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((br, bw * WORD_BITS), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rp, wp * WORD_BITS), dtype),
         interpret=interpret,
     )(p2)

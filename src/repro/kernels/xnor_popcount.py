@@ -24,9 +24,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 WORD_BITS = 32
-BM, BN, BW = 128, 128, 8  # 8 words = 256 K-bits per MXU pass
+BM, BN, BW = 128, 128, 128  # <= 128 words = 4096 K-bits per block
 
 
 def _unpack_pm1(words: jax.Array, dtype) -> jax.Array:
@@ -67,22 +68,23 @@ def xnor_gemm_packed(a_packed: jax.Array, b_packed: jax.Array,
 
     mp = pl.cdiv(m, BM) * BM
     np_ = pl.cdiv(n, BN) * BN
-    wp = pl.cdiv(w, BW) * BW
+    # A K block is either the whole packed width or a multiple of 128
+    # lane-words: the two shapes the TPU tiling accepts for a last dim.
+    bw = w if w <= BW else BW
+    wp = pl.cdiv(w, bw) * bw
     a2 = jnp.pad(a_packed.astype(jnp.uint32), ((0, mp - m), (0, wp - w)))
     b2 = jnp.pad(b_packed.astype(jnp.uint32), ((0, np_ - n), (0, wp - w)))
 
-    grid = (mp // BM, np_ // BN, wp // BW)
+    grid = (mp // BM, np_ // BN, wp // bw)
     out = pl.pallas_call(
         functools.partial(_xnor_gemm_kernel, acc_dtype=jnp.int8),
         grid=grid,
-        in_specs=[pl.BlockSpec((BM, BW), lambda i, j, k: (i, k)),
-                  pl.BlockSpec((BN, BW), lambda i, j, k: (j, k))],
+        in_specs=[pl.BlockSpec((BM, bw), lambda i, j, k: (i, k)),
+                  pl.BlockSpec((BN, bw), lambda i, j, k: (j, k))],
         out_specs=pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))) if not interpret
-        else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a2, b2)
 

@@ -30,8 +30,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,12 +44,37 @@ from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import (decode_step, empty_caches, init_params, prefill)
 from repro.models.layers import pack_bitlinear
 from repro.runtime import telemetry
+from repro.runtime.compile_cache import enable_compile_cache
 
 # config-geometry override flags -> ModelConfig fields (0 = keep)
 _CFG_OVERRIDES = (("layers", "n_layers"), ("d_model", "d_model"),
                   ("d_ff", "d_ff"), ("heads", "n_heads"),
                   ("kv_heads", "n_kv_heads"), ("d_head", "d_head"),
                   ("vocab", "vocab_size"))
+
+
+# XLA may keep a bf16 intermediate at higher precision inside a fusion
+# ("excess precision"), so on a TPU the rounding of the jitted native
+# decode step depends on how XLA fused it, while the eager step the DRIM
+# engines take rounds after every op; the binarized model turns that
+# into different tokens.  Serving rounds every op, so that the engine
+# never changes the tokens at temperature 0.
+EXACT_ROUNDING_FLAG = "--xla_allow_excess_precision=false"
+
+
+def exact_rounding() -> None:
+    """Have XLA round every op to its dtype in every program this
+    process compiles.  XLA reads `XLA_FLAGS` once, when JAX first
+    starts a backend, so this must run before that."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if EXACT_ROUNDING_FLAG in flags.split():
+        return
+    from jax._src.xla_bridge import backends_are_initialized
+    if backends_are_initialized():
+        raise RuntimeError(
+            "exact_rounding() called after JAX started a backend; set "
+            f"XLA_FLAGS={EXACT_ROUNDING_FLAG} or call it first")
+    os.environ["XLA_FLAGS"] = f"{flags} {EXACT_ROUNDING_FLAG}".strip()
 
 
 def parse_args(argv=None) -> argparse.Namespace:
@@ -287,14 +313,21 @@ def _setup(args, cfg, mesh):
                 caches=caches, prefill_s=t_prefill)
 
 
-def run_serve(args) -> Tuple[np.ndarray, Dict[str, Any]]:
+def run_serve(args, sink: Optional[Dict[str, Any]] = None,
+              ) -> Tuple[np.ndarray, Dict[str, Any]]:
     """The static-batch serving loop; returns (generated ids [B, gen],
-    stats dict)."""
+    stats dict).  `sink`, when given, receives what a caller checks the
+    served numbers against: the config, the dense params, the prompt
+    batch, the prefill logits and the decode caches they fill."""
     cfg = build_cfg(args)
     mesh = (make_host_mesh() if args.mesh == "host"
             else make_production_mesh(multi_pod=(args.mesh == "multi")))
     with mesh:
         st = _setup(args, cfg, mesh)
+        if sink is not None:
+            sink.update(cfg=cfg, params=st["dense_params"],
+                        batch=st["batch"], logits=st["logits"],
+                        caches=st["caches"], ctx_len=st["ctx_len"])
         ctx_len, key, params, caches = (st["ctx_len"], st["key"],
                                         st["params"], st["caches"])
         if args.resilient:
@@ -485,7 +518,9 @@ def run_continuous(args) -> Tuple[Dict[int, np.ndarray], Dict[str, Any]]:
 
 
 def main(argv=None):
+    exact_rounding()
     args = parse_args(argv)
+    enable_compile_cache()
     if args.telemetry or args.trace_out:
         telemetry.arm()
     if args.microbench:

@@ -19,13 +19,16 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import attention as attn
 from . import moe as moe_mod
 from . import ssm as ssm_mod
 from .layers import Params, mlp, mlp_init, rmsnorm, rmsnorm_init
 
-ZERO_AUX = {"load_balance": jnp.zeros(()), "router_z": jnp.zeros(())}
+# numpy, not jnp: importing the model must not start a JAX backend
+# (`launch.serve.exact_rounding` sets XLA flags before that).
+ZERO_AUX = {"load_balance": np.float32(0), "router_z": np.float32(0)}
 
 
 # --- dense -------------------------------------------------------------------

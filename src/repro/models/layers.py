@@ -276,7 +276,12 @@ def linear(params: Params, x: jax.Array) -> jax.Array:
 # --- rotary embeddings -------------------------------------------------------
 
 def rope_frequencies(d_head: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, d_head, 2, jnp.float32) / d_head))
+    # A constant table made on the host.  As a jnp expression it would
+    # be folded on the host under jit but run on the device eagerly, and
+    # on a TPU the two pow results differ in the last bits — enough for
+    # the eager and the jitted decode step to pick different tokens.
+    return jnp.asarray(1.0 / theta ** (np.arange(0, d_head, 2) / d_head),
+                       jnp.float32)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:

@@ -72,8 +72,9 @@ def fleet_mesh(geom: DrimGeometry, *,
                devices: Optional[Sequence] = None) -> Mesh:
     """A (chips, banks) mesh for this geometry over available devices.
 
-    Single-device fallback: a 1x1 mesh, over which `shard_map` degrades
-    to the plain path bit-for-bit.
+    It takes as many devices as `fleet_shape` can use — on one device a
+    1x1 mesh, over which `shard_map` runs the plain path bit-for-bit.
+    A caller that needs N devices checks `mesh.devices.size`.
     """
     if devices is None:
         devices = jax.devices()

@@ -41,8 +41,6 @@ from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 
 from repro.core import (AAP, CMDS_PER_AAP, DRIM_R, DrimGeometry,
@@ -188,8 +186,8 @@ def _queued_runner(programs, result_rows, n_rows, mesh, donate,
     fn = body
     if mesh is not None:
         specs = (STAGED_SPEC,) * len(programs)
-        fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=specs,
+                           out_specs=specs, check_vma=False)
     return jax.jit(fn, donate_argnums=tuple(range(len(programs)))
                    if donate else ())
 

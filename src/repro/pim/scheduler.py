@@ -50,8 +50,6 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 
 from repro.core import (AAP, DRIM_R, DrimGeometry, encode,
@@ -354,9 +352,15 @@ def wave_fn(engine: str, program: Tuple[AAP, ...],
     if engine == "pallas":
         # Lazy import: the scheduler must not pull Pallas in at import
         # time for the lax-only engines.
-        from repro.kernels.aap_interpreter import pallas_wave_fn
+        from repro.kernels.aap_interpreter import (default_interpret,
+                                                   pallas_wave_fn)
+        interpret = default_interpret()
+        # Which kernel mode each traced pallas body was built in.
+        TRACE_COUNTS["pallas_interpret" if interpret
+                     else "pallas_compiled"] += 1
         return pallas_wave_fn(program, result_rows, n_rows,
-                              faults=faults, bank_geom=bank_geom)
+                              interpret=interpret, faults=faults,
+                              bank_geom=bank_geom)
     if engine == "baseline":
         # encode directly: the enclosing runner is already memoized per
         # program, and the op-name `encoded_program` cache would only
@@ -419,8 +423,8 @@ def _wave_runner(engine: str, program: Tuple[AAP, ...],
     fn = body
     if mesh is not None:
         from repro.pim.mesh import STAGED_SPEC
-        fn = shard_map(body, mesh=mesh, in_specs=(STAGED_SPEC,),
-                       out_specs=STAGED_SPEC, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(STAGED_SPEC,),
+                           out_specs=STAGED_SPEC, check_vma=False)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 

@@ -17,7 +17,15 @@ _SRC = pathlib.Path(__file__).resolve().parents[1] / "src"
 if str(_SRC) not in sys.path:
     sys.path.insert(0, str(_SRC))
 
+import jax  # noqa: E402
+
 from repro.core import DrimGeometry  # noqa: E402
+
+# Start JAX's backend now, before any test module is imported: a module
+# that sets XLA_FLAGS for 8 fake devices at import time must not change
+# the device count of every test collected after it in the same worker.
+# Forced multi-device runs go through fresh subprocesses.
+jax.devices()
 
 
 def pytest_addoption(parser):

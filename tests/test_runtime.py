@@ -283,7 +283,6 @@ def test_param_rules_family_ssm_replicated():
 
 
 def test_zero1_spec_adds_data_axis():
-    # jax 0.4.x AbstractMesh takes ((name, size), ...) pairs
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 1)))
+    mesh = jax.sharding.AbstractMesh((2, 1), ("data", "model"))
     out = shd.zero1_spec(P(None, "model"), (8, 4), mesh)
     assert out == P("data", "model")

@@ -48,6 +48,42 @@ def test_resident_matches_tpu_tokens(tpu_run):
     assert stats_r["sample_ids"] == stats_t["sample_ids"]
 
 
+def test_run_serve_sink_holds_float32_prefill():
+    """`run_serve(args, sink)` hands over the config, params, prompts
+    and prefill logits: the served prefill of the sink's params and
+    prompts gives the served logits bitwise, and in float32 (the chip
+    smoke run's logit check) it agrees with the XLA-attention
+    reference."""
+    from repro.models import prefill
+    sink = {}
+    gen, _ = serve.run_serve(serve.parse_args(TINY), sink)
+    assert gen.shape == (2, 5)
+    assert sink["cfg"].dtype == "bfloat16"
+
+    def run(cfg):
+        return np.asarray(jax.jit(lambda p, b: prefill(p, cfg, b)[0])(
+            sink["params"], sink["batch"]), np.float32)
+    np.testing.assert_array_equal(run(sink["cfg"]),
+                                  np.asarray(sink["logits"], np.float32))
+    cfg32 = sink["cfg"].replace(dtype="float32")
+    np.testing.assert_allclose(
+        run(cfg32), run(cfg32.replace(attention_impl="xla")),
+        rtol=1e-6, atol=1e-6)
+
+
+def test_exact_rounding_needs_a_fresh_backend(monkeypatch):
+    """XLA reads XLA_FLAGS once: once a backend has started,
+    `exact_rounding` refuses rather than set a flag nothing would read;
+    with the flag already set it has nothing to do.  (The serve CLI
+    test below runs it in a fresh process.)"""
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    with pytest.raises(RuntimeError, match="after JAX started"):
+        serve.exact_rounding()
+    monkeypatch.setenv("XLA_FLAGS", serve.EXACT_ROUNDING_FLAG)
+    serve.exact_rounding()
+    assert os.environ["XLA_FLAGS"] == serve.EXACT_ROUNDING_FLAG
+
+
 def test_queued_matches_tpu_tokens(tpu_run):
     gen_t, _ = tpu_run
     gen_q, _ = _serve("--engine", "queued")
