@@ -9,10 +9,10 @@ killed mid-graph — then dumps everything the platform saw:
 
   * the metrics registry (encode/lower cache hit rates, wave trace
     counts, chaos recovery gauges) as one `snapshot()`;
-  * host wall-clock spans (compiler passes, `Lowered.run`,
-    stage/dispatch/readback) plus per-bank-queue timelines on the
-    SIMULATED DDR command clock (AAP streams, fence barriers,
-    bus-contention stalls, DEAD/requeue chaos events);
+  * host wall-clock spans (compiler passes, `run` and its
+    feeds/stage/dispatch/readback/schedule phases) plus per-bank-queue
+    timelines on the SIMULATED DDR command clock (AAP streams, fence
+    barriers, bus-contention stalls, DEAD/requeue chaos events);
   * a Chrome-trace JSON (`drim_trace.json` by default) — open it at
     https://ui.perfetto.dev or chrome://tracing: the `drim-host`
     process is wall clock, each `drim-sim <run>` process is one

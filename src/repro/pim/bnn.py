@@ -44,6 +44,7 @@ import numpy as np
 from repro.core import DRIM_R, DrimGeometry
 from repro.core.subarray import WORD_BITS
 from repro.pim.graph import BulkGraph, FusedSchedule
+from repro.runtime import telemetry
 
 # Serving reduction tile: the carry-save graph keeps ~2K+1 data rows
 # simultaneously live at the XNOR level, so K beyond the ~500-row
@@ -332,17 +333,22 @@ def serve_bnn_matmul(a_bits: np.ndarray, b_bits: np.ndarray, *,
     lanes = m * n
     total = np.zeros(lanes, np.int32)
     offset = 0
-    for kc in k_chunks(k_bits, k_tile):
-        low = serving_lowering(kc, engine=engine, geom=geom, mesh=mesh,
-                               n_queues=n_queues)
-        planes, _ = _stage_chunk_planes(a_bits[:, offset:offset + kc],
-                                        b_bits[:, offset:offset + kc])
-        outs = low.run(*planes, n_bits=lanes)
-        count = np.zeros(lanes, np.int32)
-        for i, plane in enumerate(outs):
-            bits = np.unpackbits(np.asarray(plane).view(np.uint8),
-                                 bitorder="little")
-            count += bits[:lanes].astype(np.int32) << i
-        total += 2 * count - kc
-        offset += kc
+    with telemetry.span("offload", cat="offload", tid="run", m=m, n=n,
+                        k=k_bits, engine=engine):
+        for kc in k_chunks(k_bits, k_tile):
+            low = serving_lowering(kc, engine=engine, geom=geom, mesh=mesh,
+                                   n_queues=n_queues)
+            with telemetry.span("offload.pack", cat="offload", tid="run"):
+                planes, _ = _stage_chunk_planes(
+                    a_bits[:, offset:offset + kc],
+                    b_bits[:, offset:offset + kc])
+            outs = low.run(*planes, n_bits=lanes)
+            with telemetry.span("offload.unpack", cat="offload", tid="run"):
+                count = np.zeros(lanes, np.int32)
+                for i, plane in enumerate(outs):
+                    bits = np.unpackbits(np.asarray(plane).view(np.uint8),
+                                         bitorder="little")
+                    count += bits[:lanes].astype(np.int32) << i
+            total += 2 * count - kc
+            offset += kc
     return total.reshape(m, n)

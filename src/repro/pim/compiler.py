@@ -26,6 +26,7 @@ the legacy `execute*`/`plan*` surface now delegate here.
 from __future__ import annotations
 
 import dataclasses
+import time
 import warnings
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -45,6 +46,15 @@ from repro.pim.scheduler import (N_DATA_ROWS, OP_ARITY, RESULT_ROWS,
                                  expected_results)
 import repro.pim.verify as verify_mod
 from repro.runtime import telemetry
+
+# Always-on counters at the run and lowering boundaries (registry
+# namespaces, like `LOWER_CACHE_STATS`): "run.calls" per `Lowered.run`,
+# "run.h2d_bytes" for the uint32 words of every host (numpy) operand or
+# constant plane a run turns into a device array, and "lower.us" for
+# the integer microseconds spent in `Compiled.lower`, every pass
+# included.
+RUN_STATS = telemetry.REGISTRY.counters("run")
+LOWER_STATS = telemetry.REGISTRY.counters("lower")
 
 
 def _warn_deprecated(old: str, new: str, *, stacklevel: int = 3) -> None:
@@ -129,12 +139,12 @@ def _simd_dispatch(engine_name: str) -> Callable:
     def dispatch(arrays, program, result_rows, *, n_rows, geom,
                  mesh=None, n_queues=None, faults=None):
         from repro.pim.scheduler import run_waves, stage_rows
-        with telemetry.span("stage", cat="run", tid="run",
+        with telemetry.span("run.stage", cat="run", tid="run",
                             engine=engine_name):
             staged, tiles, waves = stage_rows(
                 arrays, geom=geom,
                 mesh=mesh if engine_name == "resident" else None)
-        with telemetry.span("dispatch", cat="run", tid="run",
+        with telemetry.span("run.dispatch", cat="run", tid="run",
                             engine=engine_name, waves=waves, tiles=tiles,
                             aaps=len(program)):
             outs = run_waves(staged, program, result_rows, n_rows=n_rows,
@@ -284,19 +294,17 @@ class Compiled:
                             n_queues=n_queues, partition=partition,
                             harden=harden, faults=faults,
                             verify=verify_mod.resolve_enabled(verify))
-        if telemetry.enabled():
-            with telemetry.span("lower", cat="compiler", tid="compiler",
-                                kind=self.kind, engine=engine or ""):
-                for p in PASS_PIPELINE:
-                    with telemetry.span(f"pass:{p.name}", cat="compiler",
-                                        tid="compiler") as sp:
-                        p.fn(st)
-                        sp.set(nodes=(len(st.graph.nodes)
-                                      if st.graph is not None else 1),
-                               aaps=st.aaps)
-        else:
+        t0 = time.perf_counter()
+        with telemetry.span("lower", cat="compiler", tid="compiler",
+                            kind=self.kind, engine=engine or ""):
             for p in PASS_PIPELINE:
-                p.fn(st)
+                with telemetry.span(f"pass:{p.name}", cat="compiler",
+                                    tid="compiler") as sp:
+                    p.fn(st)
+                    sp.set(nodes=(len(st.graph.nodes)
+                                  if st.graph is not None else 1),
+                           aaps=st.aaps)
+        LOWER_STATS["us"] += int((time.perf_counter() - t0) * 1e6)
         return Lowered(
             kind=st.kind, engine=st.engine, geom=self.geom,
             mesh=st.mesh, n_queues=st.n_queues, partition=st.partition,
@@ -513,6 +521,14 @@ class EccReport:
         return self.mismatch_bits > 0
 
 
+def _device_words(x) -> jax.Array:
+    """One operand plane as flat uint32 device words; a numpy array
+    books its words in "run.h2d_bytes" (a device array moves nothing)."""
+    if isinstance(x, np.ndarray):
+        RUN_STATS["h2d_bytes"] += x.size * 4
+    return jnp.asarray(x, jnp.uint32).reshape(-1)
+
+
 class Lowered:
     """A program bound to (engine, geometry, mesh, queues, partition).
 
@@ -603,14 +619,12 @@ class Lowered:
         lowering-time default).  With `harden="ecc"` lowerings the
         detection evidence of each run lands on `self.last_ecc`.
         """
-        if not telemetry.enabled():
-            return self._run(args, n_bits, faults)
-        with telemetry.span("Lowered.run", cat="run", tid="run",
-                            kind=self.kind,
-                            engine=getattr(self.engine, "name", ""),
-                            op=self.op or "", aaps=self.aaps):
+        RUN_STATS["calls"] += 1
+        with telemetry.span("run", cat="run", tid="run", kind=self.kind,
+                            engine=self.engine.name, op=self.op or "",
+                            aaps=self.aaps):
             out = self._run(args, n_bits, faults)
-        if self.kind == "partition":
+        if self.kind == "partition" and telemetry.enabled():
             # MIMD runs also drop their simulated-clock queue timeline
             # (per-queue tracks, fences, contention stalls, chaos).
             telemetry.record_queue_timeline(self)
@@ -620,23 +634,26 @@ class Lowered:
         faults = self._resolve_faults(faults)
         if self.kind == "op":
             return self._run_op(args, n_bits, faults)
-        if self.traced is not None and not (
-                len(args) == 1 and isinstance(args[0], dict)):
-            feeds = self.traced.feeds_for(args)
-        elif len(args) == 1 and isinstance(args[0], dict):
-            feeds = dict(args[0])
-            if self.traced is not None:
-                for cname in self.traced.const_names:
-                    if cname not in feeds:
-                        n_words = int(np.prod(np.shape(
-                            next(iter(feeds.values())))))
-                        feeds[cname] = np.zeros(n_words, np.uint32)
-        else:
-            raise ValueError("graph lowering expects a feeds dict (or "
-                             "positional planes for traced programs)")
-        outs = (self._run_partitioned(feeds, n_bits, faults)
+        with telemetry.span("run.feeds", cat="run", tid="run"):
+            if self.traced is not None and not (
+                    len(args) == 1 and isinstance(args[0], dict)):
+                feeds = self.traced.feeds_for(args)
+            elif len(args) == 1 and isinstance(args[0], dict):
+                feeds = dict(args[0])
+                if self.traced is not None:
+                    for cname in self.traced.const_names:
+                        if cname not in feeds:
+                            n_words = int(np.prod(np.shape(
+                                next(iter(feeds.values())))))
+                            feeds[cname] = np.zeros(n_words, np.uint32)
+            else:
+                raise ValueError("graph lowering expects a feeds dict (or "
+                                 "positional planes for traced programs)")
+            arrays, n_words = self._check_feeds(feeds)
+            n_bits = self._resolve_n_bits(n_bits, n_words)
+        outs = (self._run_partitioned(arrays, n_bits, faults)
                 if self.kind == "partition"
-                else self._run_graph(feeds, n_bits, faults))
+                else self._run_graph(arrays, n_words, n_bits, faults))
         if self.harden is not None and "ecc" in self.harden:
             outs = self._check_ecc(dict(outs))
         if self.traced is not None:
@@ -660,37 +677,40 @@ class Lowered:
                     "n_bits out of range for the given operands")
             self.schedule = self.cost(n_bits)
             return expected_results(self.op, args)
-        ops = [jnp.asarray(x, jnp.uint32).reshape(-1) for x in operands]
-        n_words = ops[0].shape[0]
-        if any(o.shape[0] != n_words for o in ops):
-            raise ValueError("operands must have equal length")
-        if n_bits is None:
-            n_bits = n_words * WORD_BITS
-        if not 0 < n_bits <= n_words * WORD_BITS:
-            raise ValueError("n_bits out of range for the given operands")
+        with telemetry.span("run.feeds", cat="run", tid="run"):
+            ops = [_device_words(x) for x in operands]
+            n_words = ops[0].shape[0]
+            if any(o.shape[0] != n_words for o in ops):
+                raise ValueError("operands must have equal length")
+            if n_bits is None:
+                n_bits = n_words * WORD_BITS
+            if not 0 < n_bits <= n_words * WORD_BITS:
+                raise ValueError(
+                    "n_bits out of range for the given operands")
         outs, tiles, waves = self.engine.dispatch(
             ops, self.program, self.result_rows, n_rows=self.n_rows,
             geom=self.geom, mesh=self.mesh, n_queues=self.n_queues,
             faults=faults)
-        with telemetry.span("readback", cat="run", tid="run", op=self.op):
+        with telemetry.span("run.readback", cat="run", tid="run",
+                            op=self.op):
             results = tuple(outs[:, i].reshape(-1)[:n_words]
                             for i in range(len(self.result_rows)))
-        self.schedule = self.engine.lift_op(self, n_bits, tiles, waves)
+        with telemetry.span("run.schedule", cat="run", tid="run"):
+            self.schedule = self.engine.lift_op(self, n_bits, tiles, waves)
         return results
 
-    def _check_feeds(self, feeds) -> Tuple[Dict[str, jax.Array], int, int]:
+    def _check_feeds(self, feeds) -> Tuple[Dict[str, jax.Array], int]:
         names = self.graph.input_names
         missing = set(names) - set(feeds)
         extra = set(feeds) - set(names)
         if missing or extra:
             raise ValueError(f"feed mismatch: missing {sorted(missing)}, "
                              f"unexpected {sorted(extra)}")
-        arrays = {n: jnp.asarray(feeds[n], jnp.uint32).reshape(-1)
-                  for n in names}
+        arrays = {n: _device_words(feeds[n]) for n in names}
         n_words = next(iter(arrays.values())).shape[0]
         if any(a.shape[0] != n_words for a in arrays.values()):
             raise ValueError("graph inputs must have equal length")
-        return arrays, n_words, n_words * WORD_BITS
+        return arrays, n_words
 
     def _resolve_n_bits(self, n_bits, n_words):
         if n_bits is None:
@@ -705,9 +725,7 @@ class Lowered:
                 f"({(n_words - 1) * WORD_BITS}, {n_words * WORD_BITS}]")
         return n_bits
 
-    def _run_graph(self, feeds, n_bits, faults=None):
-        arrays, n_words, _ = self._check_feeds(feeds)
-        n_bits = self._resolve_n_bits(n_bits, n_words)
+    def _run_graph(self, arrays, n_words, n_bits, faults=None):
         if not self.engine.device:
             self.schedule = self.cost(n_bits)
             return graph_ref_results(
@@ -724,18 +742,17 @@ class Lowered:
                 fp.readback_rows, n_rows=fp.template_rows, geom=geom,
                 mesh=self.mesh, n_queues=self.n_queues, faults=faults)
             col = {row: i for i, row in enumerate(fp.readback_rows)}
-            with telemetry.span("readback", cat="run", tid="run",
+            with telemetry.span("run.readback", cat="run", tid="run",
                                 outputs=len(fp.device_outputs)):
                 for name, row in fp.device_outputs:
                     results[name] = outs[:, col[row]].reshape(-1)[:n_words]
-        sched = _make_fused_schedule(fp, n_bits, tiles, waves, geom)
-        self.schedule = self.engine.lift_graph(self, sched)
+        with telemetry.span("run.schedule", cat="run", tid="run"):
+            sched = _make_fused_schedule(fp, n_bits, tiles, waves, geom)
+            self.schedule = self.engine.lift_graph(self, sched)
         return results
 
-    def _run_partitioned(self, feeds, n_bits, faults=None):
+    def _run_partitioned(self, arrays, n_bits, faults=None):
         from repro.pim.queue import _execute_partitioned
-        arrays, n_words, _ = self._check_feeds(feeds)
-        n_bits = self._resolve_n_bits(n_bits, n_words)
         results, sched, chaos = _execute_partitioned(
             self.graph, arrays, gp=self.gp, geom=self.geom,
             n_bits=n_bits, mesh=self.mesh,

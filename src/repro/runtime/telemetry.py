@@ -20,13 +20,17 @@ parts:
     engine, chaos recovery latency, heartbeat liveness.
 
   * **Span tracing** — wall-clock spans over the HOST-side pipeline
-    (compiler passes, ``Lowered.run`` stage/dispatch/readback, the
-    serve decode loop and batcher waves), exported as Chrome-trace /
-    Perfetto JSON via ``export_trace(path)``.  Tracing is DISARMED by
-    default: a disarmed call site costs one branch and touches no
-    traced value, so every jitted wave body stays byte-identical to a
-    process that never imported this module (the jaxpr-equality test
-    in ``tests/test_telemetry.py`` proves it).
+    (compiler passes, ``Lowered.run`` feeds/stage/dispatch/readback/
+    schedule, the BitLinear offload's pack/unpack, the serve decode
+    loop and batcher waves), with two sinks: whenever a
+    ``jax.profiler`` session is recording, each span is a
+    ``TraceAnnotation`` named ``drim.<name>`` on the profiler's clock,
+    beside the device's ops; when armed, it is also a Chrome-trace /
+    Perfetto event exported via ``export_trace(path)``.  With neither
+    active a span is a shared no-op context and touches no traced
+    value, so every jitted wave body stays byte-identical to a process
+    that never imported this module (the jaxpr-equality test in
+    ``tests/test_telemetry.py`` proves it).
 
   * **Simulated-clock timelines** — ``queue_timeline_events`` renders
     a ``QueueSchedule`` (+ ``GraphPartition`` + ``ChaosReport``) onto
@@ -37,8 +41,8 @@ parts:
     in Perfetto / chrome://tracing.
 
 Nothing here imports jax or the pim layer at module scope, so the
-registry is safe to import from anywhere in the stack (the timeline
-renderer pulls `repro.core` lazily).
+registry is safe to import from anywhere in the stack (spans pull
+``jax.profiler`` and the timeline renderer `repro.core` lazily).
 
 Arming: ``telemetry.arm()`` / ``disarm()`` / the ``armed()`` context,
 or set ``DRIM_TELEMETRY=1`` in the environment before import (how the
@@ -210,22 +214,24 @@ def snapshot() -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# Span tracing (host wall-clock, Chrome trace format)
+# Span tracing (host wall-clock: the profiler's trace and a Chrome buffer)
 # ---------------------------------------------------------------------------
 
 HOST_PID = 1          # wall-clock spans (compiler, runs, serving)
 SIM_PID = 2           # simulated-DDR-clock queue timelines
+PROFILER_PREFIX = "drim."   # span names in a jax.profiler trace
 
 _ARMED = os.environ.get("DRIM_TELEMETRY", "0") not in ("", "0")
 _EPOCH = time.perf_counter()
 _EVENTS: List[dict] = []
 _TIDS: Dict[Tuple[int, str], int] = {}
+_ANNOTATION = None    # jax.profiler.TraceAnnotation, imported on first span
 
 
 def enabled() -> bool:
-    """True when span tracing is armed.  Call sites on hot paths gate
-    on this single branch; everything else (metrics counters) is
-    always-on and jit-invisible."""
+    """True when the Chrome-buffer tracer is armed.  Everything else
+    (metrics counters, profiler annotations while a trace records) is
+    independent of it and jit-invisible."""
     return _ARMED
 
 
@@ -266,26 +272,44 @@ def _tid(pid: int, name: str) -> int:
     return t
 
 
-class _Span:
-    __slots__ = ("_name", "_cat", "_tid", "_args", "_t0")
+def _annotation_type():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
-    def __init__(self, name, cat, tid, args):
+
+class _Span:
+    __slots__ = ("_name", "_cat", "_tid", "_args", "_t0", "_ann",
+                 "_chrome")
+
+    def __init__(self, name, cat, tid, args, annotation, chrome):
         self._name, self._cat, self._tid, self._args = name, cat, tid, args
+        self._ann, self._chrome = annotation, chrome
 
     def set(self, **args):
-        """Attach args discovered mid-span (visible in the trace)."""
+        """Attach args discovered mid-span (visible in the Chrome trace;
+        a profiler annotation keeps the args it was opened with)."""
         self._args.update(args)
         return self
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
-        _EVENTS.append({"name": self._name, "cat": self._cat, "ph": "X",
-                        "ts": self._t0, "dur": _now_us() - self._t0,
-                        "pid": HOST_PID, "tid": _tid(HOST_PID, self._tid),
-                        "args": self._args})
+        t1 = _now_us()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._chrome:
+            _EVENTS.append({"name": self._name, "cat": self._cat,
+                            "ph": "X", "ts": self._t0,
+                            "dur": t1 - self._t0, "pid": HOST_PID,
+                            "tid": _tid(HOST_PID, self._tid),
+                            "args": self._args})
         return False
 
 
@@ -307,12 +331,18 @@ _NULL_SPAN = _NullSpan()
 
 def span(name: str, *, cat: str = "host", tid: str = "main",
          **args: Any):
-    """Wall-clock span context.  Disarmed: returns a shared no-op
-    context (one branch, zero allocation beyond the call itself) —
-    never touches traced values, so jitted code is unaffected."""
-    if not _ARMED:
+    """Wall-clock span context.  While a `jax.profiler` trace records,
+    the span is a ``TraceAnnotation`` named ``drim.<name>`` carrying
+    `args` as metadata; while armed, it is also a Chrome-buffer event
+    named `name`.  With neither, it is a shared no-op context (one
+    profiler check, no allocation beyond the call itself) that never
+    touches traced values, so jitted code is unaffected."""
+    ann_type = _ANNOTATION or _annotation_type()
+    recording = ann_type.is_enabled()
+    if not (recording or _ARMED):
         return _NULL_SPAN
-    return _Span(name, cat, tid, args)
+    ann = ann_type(PROFILER_PREFIX + name, **args) if recording else None
+    return _Span(name, cat, tid, args, ann, _ARMED)
 
 
 def event(name: str, *, cat: str = "host", tid: str = "main",

@@ -203,6 +203,115 @@ def test_disarmed_pipeline_emits_no_events(small_geom):
         assert telemetry.trace_events() == []
 
 
+def test_span_is_shared_null_when_neither_sink_is_active():
+    with telemetry.armed(False):
+        assert telemetry.span("run") is telemetry.span("lower", aaps=3)
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler clock: `drim.*` TraceAnnotations in a jax trace
+# ---------------------------------------------------------------------------
+
+RUN_SPANS = ["drim.run.feeds", "drim.run.stage", "drim.run.dispatch",
+             "drim.run.readback", "drim.run.schedule"]
+
+
+def _profiled_spans(tmp_path, block):
+    """`block()` under a real `jax.profiler` trace; returns the host
+    `drim.*` events as (name, parent name) in start order."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        block()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = sorted(((e.start_ns, e.end_ns, e.name)
+                    for plane in ProfileData.from_file(path[0]).planes
+                    if plane.name.startswith("/host:")
+                    for line in plane.lines for e in line.events
+                    if e.name.startswith("drim.")),
+                   key=lambda x: (x[0], -x[1]))
+    out, stack = [], []
+    for s, t, name in spans:
+        while stack and stack[-1][1] <= s:
+            stack.pop()
+        out.append((name, stack[-1][2] if stack else None))
+        stack.append((s, t, name))
+    return out
+
+
+def test_run_spans_on_the_profiler_clock(tmp_path, small_geom):
+    """A K=4 traced program: `lower` and its passes, then one `run`
+    holding the five phases, all named `drim.*` in the trace, armed or
+    not."""
+    from repro.pim.bnn import bitlinear_kernel
+    rng = np.random.default_rng(3)
+    planes = [rng.integers(0, 1 << 32, N_WORDS, dtype=np.uint32)
+              for _ in range(8)]
+    got = {}
+
+    def block():
+        low = drim.compile(bitlinear_kernel(4).trace(),
+                           geom=small_geom).lower("resident")
+        jax.block_until_ready(low.run(*planes))
+
+    for on in (False, True):
+        with telemetry.armed(on):
+            got[on] = _profiled_spans(tmp_path / str(on), block)
+    assert got[False] == got[True]
+    assert got[False] == (
+        [("drim.lower", None)]
+        + [(f"drim.pass:{p.name}", "drim.lower") for p in PASS_PIPELINE]
+        + [("drim.run", None)] + [(n, "drim.run") for n in RUN_SPANS])
+
+
+def test_offload_spans_on_the_profiler_clock(tmp_path, small_geom):
+    """`serve_bnn_matmul` over two K chunks: per chunk, pack, the run
+    and its phases, unpack, all inside one `drim.offload`."""
+    from repro.pim.bnn import serve_bnn_matmul, serving_lowering
+    rng = np.random.default_rng(4)
+    a = rng.integers(0, 2, (2, 16), dtype=np.uint8)
+    b = rng.integers(0, 2, (8, 16), dtype=np.uint8)
+    serving_lowering(8, geom=small_geom)          # lowered before the trace
+    got = _profiled_spans(
+        tmp_path, lambda: serve_bnn_matmul(a, b, geom=small_geom, k_tile=8))
+    chunk = ([("drim.offload.pack", "drim.offload"),
+              ("drim.run", "drim.offload")]
+             + [(n, "drim.run") for n in RUN_SPANS]
+             + [("drim.offload.unpack", "drim.offload")])
+    assert got == [("drim.offload", None)] + chunk + chunk
+
+
+def test_run_counters_book_host_planes(small_geom):
+    """"run.h2d_bytes" counts the uint32 words of host planes only:
+    device operands move nothing, a traced program's host-made constant
+    plane does; "lower.us" counts every lowering."""
+    from repro.pim.bnn import bitlinear_kernel
+    from repro.pim.compiler import LOWER_STATS, RUN_STATS
+    rng = np.random.default_rng(5)
+    host = [rng.integers(0, 1 << 32, N_WORDS, dtype=np.uint32)
+            for _ in range(8)]
+    dev = [jax.numpy.asarray(p) for p in host]
+    with telemetry.fresh():
+        xnor = drim.compile("xnor2", geom=small_geom).lower("resident")
+        k4 = drim.compile(bitlinear_kernel(4).trace(),
+                          geom=small_geom).lower("resident")
+        assert LOWER_STATS["us"] > 0
+        xnor.run(*dev[:2])
+        assert dict(RUN_STATS) == {"calls": 1}
+        xnor.run(*host[:2])
+        assert RUN_STATS["h2d_bytes"] == 2 * N_WORDS * 4
+        k4.run(*dev)                           # the zero plane alone
+        assert RUN_STATS["h2d_bytes"] == 3 * N_WORDS * 4
+        k4.run(*host)                          # 8 planes and the zero plane
+        assert RUN_STATS["h2d_bytes"] == 12 * N_WORDS * 4
+        assert RUN_STATS["calls"] == 4
+        snap = telemetry.REGISTRY.snapshot()["counters"]
+        assert snap["run.calls"] == 4 and snap["lower.us"] > 0
+
+
 # ---------------------------------------------------------------------------
 # Bit-exactness with telemetry armed
 # ---------------------------------------------------------------------------
@@ -288,7 +397,7 @@ def test_perfetto_trace_schema(tmp_path, small_geom):
             assert e["pid"] == lower["pid"] and e["tid"] == lower["tid"]
             assert e["ts"] >= lower["ts"] - 1e-6
             assert e["ts"] + e["dur"] <= lower["ts"] + lower["dur"] + 1e-6
-    assert "Lowered.run" in names
+    assert "run" in names
 
     # -- each recorded run renders its own sim process with one track
     #    per bank queue
