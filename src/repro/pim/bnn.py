@@ -34,6 +34,7 @@ on the simulator and returns the int32 dot products, bit-exact vs
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 from typing import Dict, List, Optional, Tuple
@@ -145,22 +146,14 @@ def stage_bnn_planes(a_bits: np.ndarray, b_bits: np.ndarray,
     n_bits = M*N to `execute_graph` to mark the ragged tail).
     Returns (feeds, n_lanes).
     """
-    m, k_bits = a_bits.shape
-    n, kb2 = b_bits.shape
-    if k_bits != kb2:
+    k_bits = a_bits.shape[1]
+    if k_bits != b_bits.shape[1]:
         raise ValueError("operand K dimensions differ")
-    lanes = m * n
-    n_words = -(-lanes // WORD_BITS)
-    feeds: Dict[str, np.ndarray] = {}
-    for k in range(k_bits):
-        pa = np.repeat(a_bits[:, k].astype(np.uint8), n)
-        pb = np.tile(b_bits[:, k].astype(np.uint8), m)
-        for name, plane in ((f"a{k}", pa), (f"b{k}", pb)):
-            padded = np.zeros(n_words * WORD_BITS, np.uint8)
-            padded[:lanes] = plane
-            feeds[name] = np.packbits(padded, bitorder="little") \
-                .view(np.uint32)
-    feeds["zero"] = np.zeros(n_words, np.uint32)
+    planes, lanes = _stage_chunk_planes(a_bits, b_bits)
+    names = ([f"a{k}" for k in range(k_bits)]
+             + [f"b{k}" for k in range(k_bits)])
+    feeds: Dict[str, np.ndarray] = dict(zip(names, planes))
+    feeds["zero"] = np.zeros_like(planes[0])
     return feeds, lanes
 
 
@@ -287,25 +280,55 @@ def serving_lowering(k_bits: int, *, engine: str = "resident",
         mesh=mesh, n_queues=n_queues)
 
 
-def _stage_chunk_planes(a_bits: np.ndarray,
-                        b_bits: np.ndarray) -> Tuple[List[np.ndarray], int]:
+# Bytes of unpacked lane bits (one uint8 a lane) `_stage_chunk_planes`
+# fills at once: a decode GEMM's group stages in one pass per operand,
+# a prefill-sized GEMM in slabs of planes, so the transient stays small
+# beside the packed planes.
+_STAGE_SLAB_BYTES = 1 << 25
+
+# Always-on counters of `serve_bnn_matmul` (registry namespace
+# "offload", beside `compiler.RUN_STATS`): "offload.chunks" per K chunk
+# served and "offload.runs" per `Lowered.run` it issues.
+OFFLOAD_STATS = telemetry.REGISTRY.counters("offload")
+
+
+def _stage_chunk_planes(a_bits: np.ndarray, b_bits: np.ndarray,
+                        group: int = 1) -> Tuple[List[np.ndarray], int]:
     """`stage_bnn_planes` layout as the positional plane list the traced
-    kernel takes: a-planes then b-planes, lane m*N+n = output (m, n)."""
-    m, k_bits = a_bits.shape
+    kernel takes (a-planes then b-planes), for `group` K chunks of equal
+    width kc laid side by side: a_bits [M, group*kc], b_bits
+    [N, group*kc].  Lane c*M*N + m*N + n holds output (m, n) of chunk
+    c; plane a_k holds A[:, c*kc + k] repeated N times and plane b_k
+    holds B[:, c*kc + k] tiled M times, for each c.  Returns (planes,
+    lanes = group*M*N)."""
+    m, width = a_bits.shape
     n = b_bits.shape[0]
-    lanes = m * n
+    kc = width // group
+    lanes = group * m * n
     n_words = -(-lanes // WORD_BITS)
-    planes: List[np.ndarray] = []
-    for source, layout in ((a_bits, "repeat"), (b_bits, "tile")):
-        for k in range(k_bits):
-            lane_bits = (np.repeat(source[:, k].astype(np.uint8), n)
-                         if layout == "repeat"
-                         else np.tile(source[:, k].astype(np.uint8), m))
-            padded = np.zeros(n_words * WORD_BITS, np.uint8)
-            padded[:lanes] = lane_bits
-            planes.append(np.packbits(padded, bitorder="little")
-                          .view(np.uint32))
-    return planes, lanes
+    # [kc, group, M, 1] and [kc, group, 1, N], broadcast over the lanes
+    a_t, b_t = (np.ascontiguousarray(x.T).reshape(group, kc, -1)
+                .swapaxes(0, 1) for x in (a_bits, b_bits))
+    sides = (a_t[..., None], b_t[:, :, None])
+    step = max(1, min(kc, _STAGE_SLAB_BYTES // (n_words * WORD_BITS)))
+    bits = np.zeros((step, n_words * WORD_BITS), np.uint8)
+    words = np.empty((2, kc, n_words), np.uint32)
+    for side, src in enumerate(sides):
+        for k0 in range(0, kc, step):
+            k1 = min(kc, k0 + step)
+            # a view of the slab's lanes: the operand bits land in place
+            bits[:k1 - k0, :lanes].reshape(k1 - k0, group, m, n)[...] = \
+                src[k0:k1]
+            words[side, k0:k1] = np.packbits(
+                bits[:k1 - k0], axis=-1, bitorder="little").view(np.uint32)
+    return list(words.reshape(2 * kc, n_words)), lanes
+
+
+def _chunks_per_run(lanes: int, geom: DrimGeometry) -> int:
+    """K chunks of one GEMM that share a `Lowered.run`: as many
+    `lanes`-wide chunks as one wave holds, so a group never needs more
+    waves than one chunk; 1 once a chunk alone fills a wave."""
+    return max(1, geom.parallel_bits // lanes)
 
 
 def serve_bnn_matmul(a_bits: np.ndarray, b_bits: np.ndarray, *,
@@ -317,10 +340,13 @@ def serve_bnn_matmul(a_bits: np.ndarray, b_bits: np.ndarray, *,
 
     a_bits [M, K], b_bits [N, K] sign bits in {0, 1}; returns C [M, N]
     int32 = the ±1 dot, bit-exact vs `kernels/ref.py:xnor_gemm_ref`.
-    The reduction dim tiles into `k_chunks` (sub-array row budget);
-    each chunk runs the cached carry-save `drim.jit` kernel and the
-    partial dots sum exactly: sum over chunks of (2*pop_c - K_c)
-    == 2*popcount(XNOR) - K.
+    The reduction dim tiles into `k_chunks` (sub-array row budget),
+    each width one cached carry-save `drim.jit` kernel.  Chunks of one
+    width run in groups of `_chunks_per_run`, side by side in the lanes
+    of one run (`_stage_chunk_planes`), so a decode GEMM whose M*N lanes
+    fill a sliver of a wave pays for one run, not one per chunk; a
+    ragged last chunk runs alone.  The partial dots sum exactly: sum
+    over chunks of (2*pop_c - K_c) == 2*popcount(XNOR) - K.
     """
     a_bits = np.asarray(a_bits, np.uint8)
     b_bits = np.asarray(b_bits, np.uint8)
@@ -335,20 +361,30 @@ def serve_bnn_matmul(a_bits: np.ndarray, b_bits: np.ndarray, *,
     offset = 0
     with telemetry.span("offload", cat="offload", tid="run", m=m, n=n,
                         k=k_bits, engine=engine):
-        for kc in k_chunks(k_bits, k_tile):
+        widths = collections.Counter(k_chunks(k_bits, k_tile))
+        for kc, n_chunks in widths.items():
             low = serving_lowering(kc, engine=engine, geom=geom, mesh=mesh,
                                    n_queues=n_queues)
-            with telemetry.span("offload.pack", cat="offload", tid="run"):
-                planes, _ = _stage_chunk_planes(
-                    a_bits[:, offset:offset + kc],
-                    b_bits[:, offset:offset + kc])
-            outs = low.run(*planes, n_bits=lanes)
-            with telemetry.span("offload.unpack", cat="offload", tid="run"):
-                count = np.zeros(lanes, np.int32)
-                for i, plane in enumerate(outs):
-                    bits = np.unpackbits(np.asarray(plane).view(np.uint8),
-                                         bitorder="little")
-                    count += bits[:lanes].astype(np.int32) << i
-            total += 2 * count - kc
-            offset += kc
+            per_run = _chunks_per_run(lanes, low.geom)
+            for first in range(0, n_chunks, per_run):
+                group = min(per_run, n_chunks - first)
+                end = offset + group * kc
+                with telemetry.span("offload.pack", cat="offload",
+                                    tid="run"):
+                    planes, run_lanes = _stage_chunk_planes(
+                        a_bits[:, offset:end], b_bits[:, offset:end], group)
+                outs = low.run(*planes, n_bits=run_lanes)
+                OFFLOAD_STATS["runs"] += 1
+                OFFLOAD_STATS["chunks"] += group
+                with telemetry.span("offload.unpack", cat="offload",
+                                    tid="run"):
+                    pop = np.zeros(run_lanes, np.int32)
+                    for i, plane in enumerate(outs):
+                        bits = np.unpackbits(
+                            np.asarray(plane).view(np.uint8),
+                            bitorder="little")
+                        pop += bits[:run_lanes].astype(np.int32) << i
+                    total += (2 * pop.reshape(group, lanes)
+                              - kc).sum(axis=0, dtype=np.int32)
+                offset = end
     return total.reshape(m, n)

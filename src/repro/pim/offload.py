@@ -501,9 +501,11 @@ def serving_verdict(m: int, n: int, k_bits: int, *,
     Uses the SAME cached lowerings `pim.bnn.serve_bnn_matmul` executes
     (via `compiler.lower_cached`), priced by `build_verdict` at
     n_bits = m*n lanes per K chunk, with every row field summed across
-    the serialized chunks — which is exactly how the serving path runs
-    them.  The TPU roofline row sums the same way, so the Verdict
-    compares like with like.
+    the serialized chunks: it prices each chunk as a run of its own,
+    which is what a DRIM that spends a wave per chunk would do (the
+    serving path packs a GEMM's chunks into the lanes of one run).  The
+    TPU roofline row sums the same way, so the Verdict compares like
+    with like.
     """
     from repro.pim.bnn import k_chunks, serving_lowering
     chunks = k_chunks(k_bits, k_tile)

@@ -31,6 +31,7 @@ import sys
 
 import jax
 import numpy as np
+import pytest
 
 import drim
 from drim import DrimGeometry, FaultModel, PASS_PIPELINE
@@ -267,21 +268,26 @@ def test_run_spans_on_the_profiler_clock(tmp_path, small_geom):
         + [("drim.run", None)] + [(n, "drim.run") for n in RUN_SPANS])
 
 
-def test_offload_spans_on_the_profiler_clock(tmp_path, small_geom):
-    """`serve_bnn_matmul` over two K chunks: per chunk, pack, the run
-    and its phases, unpack, all inside one `drim.offload`."""
+@pytest.mark.parametrize("m,n,runs", [(2, 8, 1), (64, 64, 2)],
+                         ids=["packed", "wave-sized"])
+def test_offload_spans_on_the_profiler_clock(tmp_path, small_geom, m, n,
+                                             runs):
+    """`serve_bnn_matmul` over two K chunks: per run, pack, the run and
+    its phases, unpack, all inside one `drim.offload`.  Two 16-lane
+    chunks share one run; two 4096-lane chunks (a whole `small_geom`
+    wave each) run one at a time."""
     from repro.pim.bnn import serve_bnn_matmul, serving_lowering
     rng = np.random.default_rng(4)
-    a = rng.integers(0, 2, (2, 16), dtype=np.uint8)
-    b = rng.integers(0, 2, (8, 16), dtype=np.uint8)
+    a = rng.integers(0, 2, (m, 16), dtype=np.uint8)
+    b = rng.integers(0, 2, (n, 16), dtype=np.uint8)
     serving_lowering(8, geom=small_geom)          # lowered before the trace
     got = _profiled_spans(
         tmp_path, lambda: serve_bnn_matmul(a, b, geom=small_geom, k_tile=8))
-    chunk = ([("drim.offload.pack", "drim.offload"),
-              ("drim.run", "drim.offload")]
-             + [(n, "drim.run") for n in RUN_SPANS]
-             + [("drim.offload.unpack", "drim.offload")])
-    assert got == [("drim.offload", None)] + chunk + chunk
+    run = ([("drim.offload.pack", "drim.offload"),
+            ("drim.run", "drim.offload")]
+           + [(name, "drim.run") for name in RUN_SPANS]
+           + [("drim.offload.unpack", "drim.offload")])
+    assert got == [("drim.offload", None)] + run * runs
 
 
 def test_run_counters_book_host_planes(small_geom):
