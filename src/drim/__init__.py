@@ -35,7 +35,7 @@ from repro.pim.frontend import (BitTensor, JittedFunction, TraceError,
                                 jit, maj, popcount, select, xnor)
 from repro.pim.graph import BulkGraph
 from repro.pim.harden import HARDEN_SCHEMES, harden_graph
-from repro.pim.mesh import fleet_mesh
+from repro.pim.mesh import WORDS_SPEC, fleet_mesh, words_sharding
 from repro.pim.offload import (TpuCost, Verdict, VerdictRow, build_verdict,
                                tpu_cost)
 from repro.pim.queue import ChaosReport
@@ -49,8 +49,8 @@ __all__ = [
     "EngineRegistry", "FaultModel", "HARDEN_SCHEMES", "JittedFunction",
     "Lowered", "PARTITIONERS", "PASS_PIPELINE", "TpuCost", "TraceError",
     "TracedProgram", "Verdict", "VerdictRow", "VerifyError",
-    "VerifyReport", "build_verdict", "compile", "copy", "csa_reduce",
-    "engines", "fleet_mesh", "full_add", "get_engine", "harden_graph",
-    "jit", "lower", "maj", "obs", "popcount", "select", "tpu_cost",
-    "verify", "verify_lowered", "xnor",
+    "VerifyReport", "WORDS_SPEC", "build_verdict", "compile", "copy",
+    "csa_reduce", "engines", "fleet_mesh", "full_add", "get_engine",
+    "harden_graph", "jit", "lower", "maj", "obs", "popcount", "select",
+    "tpu_cost", "verify", "verify_lowered", "words_sharding", "xnor",
 ]

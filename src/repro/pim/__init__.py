@@ -12,8 +12,8 @@ from .scheduler import (ENGINES, OP_ARITY, REF_OP, RESULT_ROWS, Schedule,
                         execute, execute_oplist, expected_results,
                         fresh_encode_cache, plan_schedule, random_operands,
                         run_waves, run_waves_baseline, stage_rows, wave_fn)
-from .mesh import (DEVICE_SPEC, STAGED_SPEC, fleet_mesh, fleet_shape,
-                   shard_device, shard_staged)
+from .mesh import (DEVICE_SPEC, STAGED_SPEC, WORDS_SPEC, fleet_mesh,
+                   fleet_shape, shard_device, shard_staged, words_sharding)
 from .graph import (BulkGraph, FusedProgram, FusedSchedule, GraphPartition,
                     QueueSegment, ValueRef, compile_graph, execute_graph,
                     graph_ref_results, partition_graph,

@@ -44,15 +44,18 @@ from repro.pim.graph import (DEFAULT_ROW_BUDGET, BulkGraph, FusedProgram,
 from repro.pim.scheduler import (N_DATA_ROWS, OP_ARITY, RESULT_ROWS,
                                  Schedule, _ceil_div, encoded_program,
                                  expected_results)
+import repro.pim.mesh as mesh_mod
 import repro.pim.verify as verify_mod
 from repro.runtime import telemetry
 
 # Always-on counters at the run and lowering boundaries (registry
 # namespaces, like `LOWER_CACHE_STATS`): "run.calls" per `Lowered.run`,
 # "run.h2d_bytes" for the uint32 words of every host (numpy) operand or
-# constant plane a run turns into a device array, and "lower.us" for
-# the integer microseconds spent in `Compiled.lower`, every pass
-# included.
+# constant plane a run turns into a device array, "run.xchip_bytes" for
+# the operand and result bytes a run over a mesh of several devices
+# delivers to a device other than the one that holds or simulates them,
+# and "lower.us" for the integer microseconds spent in
+# `Compiled.lower`, every pass included.
 RUN_STATS = telemetry.REGISTRY.counters("run")
 LOWER_STATS = telemetry.REGISTRY.counters("lower")
 
@@ -83,11 +86,12 @@ class Engine:
     numbers lift into this engine's cost model.
 
     `dispatch(arrays, program, result_rows, n_rows=, geom=, mesh=,
-    n_queues=) -> (outs, tiles, waves)` runs one uniform program over
-    the staged payload; `lift_op` / `lift_graph` wrap measured (or
-    closed-form) tiling into the engine's Schedule flavour.  `device`
-    is False for comparator engines (TPU roofline) that never touch the
-    simulated fleet.
+    n_queues=, faults=, blocks=) -> (outs, tiles, waves)` runs one
+    uniform program over the staged payload (`blocks`: the words are in
+    the mesh's block layout, `pim.mesh.words_layout`); `lift_op` /
+    `lift_graph` wrap measured (or closed-form) tiling into the
+    engine's Schedule flavour.  `device` is False for comparator
+    engines (TPU roofline) that never touch the simulated fleet.
     """
 
     name: str
@@ -137,13 +141,14 @@ def engines() -> Tuple[str, ...]:
 
 def _simd_dispatch(engine_name: str) -> Callable:
     def dispatch(arrays, program, result_rows, *, n_rows, geom,
-                 mesh=None, n_queues=None, faults=None):
+                 mesh=None, n_queues=None, faults=None, blocks=False):
         from repro.pim.scheduler import run_waves, stage_rows
         with telemetry.span("run.stage", cat="run", tid="run",
                             engine=engine_name):
             staged, tiles, waves = stage_rows(
                 arrays, geom=geom,
-                mesh=mesh if engine_name == "resident" else None)
+                mesh=mesh if engine_name == "resident" else None,
+                blocks=blocks)
         with telemetry.span("run.dispatch", cat="run", tid="run",
                             engine=engine_name, waves=waves, tiles=tiles,
                             aaps=len(program)):
@@ -154,7 +159,7 @@ def _simd_dispatch(engine_name: str) -> Callable:
 
 
 def _queued_dispatch(arrays, program, result_rows, *, n_rows, geom,
-                     mesh=None, n_queues=None, faults=None):
+                     mesh=None, n_queues=None, faults=None, blocks=False):
     from repro.pim.queue import dispatch_uniform_queued
     return dispatch_uniform_queued(arrays, program, result_rows,
                                    n_rows=n_rows, geom=geom, mesh=mesh,
@@ -162,7 +167,7 @@ def _queued_dispatch(arrays, program, result_rows, *, n_rows, geom,
 
 
 def _pallas_dispatch(arrays, program, result_rows, *, n_rows, geom,
-                     mesh=None, n_queues=None, faults=None):
+                     mesh=None, n_queues=None, faults=None, blocks=False):
     if mesh is not None:
         raise ValueError("engine 'pallas' runs unsharded — use "
                          "engine='resident' for shard_map fleet meshes")
@@ -529,6 +534,52 @@ def _device_words(x) -> jax.Array:
     return jnp.asarray(x, jnp.uint32).reshape(-1)
 
 
+def _plane_words(planes, what: str) -> int:
+    """The common word count of a run's operand planes."""
+    sizes = {int(np.size(x)) for x in planes}
+    if len(sizes) != 1:
+        raise ValueError(f"{what} must have equal length")
+    return sizes.pop()
+
+
+def _mesh_words(planes, mesh, layout: str) -> list:
+    """Operand planes of a resident run over a mesh of several devices,
+    as flat uint32 words in `layout` ("blocks" or "whole",
+    `pim.mesh.words_layout`).  Planes held otherwise are placed once
+    (span "run.place" for the blocks); the bytes that reach a device
+    other than the one holding them go to "run.xchip_bytes".  The whole
+    path places every operand whole on every device, and that is what
+    is counted."""
+    if layout == "whole":
+        whole = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+        ops = [_device_words(x) for x in planes]
+        RUN_STATS["xchip_bytes"] += sum(mesh_mod.missing_bytes(o, whole)
+                                        for o in ops)
+        return [jax.device_put(o, whole) for o in ops]
+    target = mesh_mod.words_sharding(mesh)
+
+    def in_layout(x) -> bool:
+        return (isinstance(x, jax.Array) and x.ndim == 1
+                and x.dtype == jnp.uint32
+                and x.sharding.is_equivalent_to(target, 1))
+
+    ops, moved = list(planes), 0
+    foreign = [i for i, x in enumerate(ops) if not in_layout(x)]
+    if foreign:
+        with telemetry.span("run.place", cat="run", tid="run",
+                            planes=len(foreign)):
+            for i in foreign:
+                if isinstance(ops[i], jax.Array):
+                    w = jnp.asarray(ops[i], jnp.uint32).reshape(-1)
+                    moved += mesh_mod.missing_bytes(w, target)
+                else:
+                    w = np.asarray(ops[i], np.uint32).reshape(-1)
+                    RUN_STATS["h2d_bytes"] += w.size * 4
+                ops[i] = jax.device_put(w, target)
+    RUN_STATS["xchip_bytes"] += moved
+    return ops
+
+
 class Lowered:
     """A program bound to (engine, geometry, mesh, queues, partition).
 
@@ -549,6 +600,10 @@ class Lowered:
         self.engine = engine
         self.geom = geom
         self.mesh = mesh
+        # The mesh a run's operand and result words are laid over
+        # (`pim.mesh.words_layout`): the resident engine's; the queued
+        # engine stages its words row-major from wherever they are.
+        self.words_mesh = mesh if engine.name == "resident" else None
         self.n_queues = n_queues
         self.partition = partition
         self.row_budget = row_budget
@@ -618,11 +673,20 @@ class Lowered:
         faults: a `core.FaultModel` for THIS run only (overrides the
         lowering-time default).  With `harden="ecc"` lowerings the
         detection evidence of each run lands on `self.last_ecc`.
+
+        Over a fleet mesh of several devices (resident engine), operand
+        and result words take the mesh's block layout
+        (`pim.mesh.words_sharding`): each device holds, simulates and
+        returns one contiguous block.  Operands already in it move
+        nothing between devices; others are placed there first.  A word
+        count that does not divide by the device count keeps the
+        replicated path.  "run.xchip_bytes" counts what crosses devices.
         """
         RUN_STATS["calls"] += 1
         with telemetry.span("run", cat="run", tid="run", kind=self.kind,
                             engine=self.engine.name, op=self.op or "",
-                            aaps=self.aaps):
+                            aaps=self.aaps,
+                            devices=mesh_mod.n_devices(self.mesh)):
             out = self._run(args, n_bits, faults)
         if self.kind == "partition" and telemetry.enabled():
             # MIMD runs also drop their simulated-clock queue timeline
@@ -649,11 +713,12 @@ class Lowered:
             else:
                 raise ValueError("graph lowering expects a feeds dict (or "
                                  "positional planes for traced programs)")
-            arrays, n_words = self._check_feeds(feeds)
+            arrays, n_words, layout = self._check_feeds(feeds)
             n_bits = self._resolve_n_bits(n_bits, n_words)
         outs = (self._run_partitioned(arrays, n_bits, faults)
                 if self.kind == "partition"
-                else self._run_graph(arrays, n_words, n_bits, faults))
+                else self._run_graph(arrays, n_words, layout, n_bits,
+                                     faults))
         if self.harden is not None and "ecc" in self.harden:
             outs = self._check_ecc(dict(outs))
         if self.traced is not None:
@@ -678,10 +743,9 @@ class Lowered:
             self.schedule = self.cost(n_bits)
             return expected_results(self.op, args)
         with telemetry.span("run.feeds", cat="run", tid="run"):
-            ops = [_device_words(x) for x in operands]
-            n_words = ops[0].shape[0]
-            if any(o.shape[0] != n_words for o in ops):
-                raise ValueError("operands must have equal length")
+            n_words = _plane_words(operands, "operands")
+            layout = mesh_mod.words_layout(self.words_mesh, n_words)
+            ops = self._words(operands, layout)
             if n_bits is None:
                 n_bits = n_words * WORD_BITS
             if not 0 < n_bits <= n_words * WORD_BITS:
@@ -690,27 +754,48 @@ class Lowered:
         outs, tiles, waves = self.engine.dispatch(
             ops, self.program, self.result_rows, n_rows=self.n_rows,
             geom=self.geom, mesh=self.mesh, n_queues=self.n_queues,
-            faults=faults)
+            faults=faults, blocks=layout == "blocks")
         with telemetry.span("run.readback", cat="run", tid="run",
                             op=self.op):
-            results = tuple(outs[:, i].reshape(-1)[:n_words]
-                            for i in range(len(self.result_rows)))
+            results = self._readback(outs, range(len(self.result_rows)),
+                                     n_words, layout)
         with telemetry.span("run.schedule", cat="run", tid="run"):
             self.schedule = self.engine.lift_op(self, n_bits, tiles, waves)
         return results
 
-    def _check_feeds(self, feeds) -> Tuple[Dict[str, jax.Array], int]:
+    def _words(self, planes, layout: str) -> list:
+        """Operand planes as flat uint32 device words in the run's
+        `layout` (`pim.mesh.words_layout`)."""
+        if layout == "one":
+            return [_device_words(x) for x in planes]
+        return _mesh_words(planes, self.words_mesh, layout)
+
+    def _readback(self, outs, cols, n_words,
+                  layout: str) -> Tuple[jax.Array, ...]:
+        """Result planes `cols` of a dispatch's readback block as flat
+        words in the run's `layout`: "blocks" each device trimming its
+        own slab (`pim.mesh.read_blocks`), "whole" every result whole on
+        every device."""
+        if layout == "blocks":
+            return mesh_mod.read_blocks(outs, cols, n_words,
+                                        self.words_mesh)
+        if layout == "whole":
+            d = mesh_mod.n_devices(self.words_mesh)
+            RUN_STATS["xchip_bytes"] += (d - 1) * n_words * 4 * len(cols)
+        return tuple(outs[:, c].reshape(-1)[:n_words] for c in cols)
+
+    def _check_feeds(self, feeds
+                     ) -> Tuple[Dict[str, jax.Array], int, str]:
         names = self.graph.input_names
         missing = set(names) - set(feeds)
         extra = set(feeds) - set(names)
         if missing or extra:
             raise ValueError(f"feed mismatch: missing {sorted(missing)}, "
                              f"unexpected {sorted(extra)}")
-        arrays = {n: _device_words(feeds[n]) for n in names}
-        n_words = next(iter(arrays.values())).shape[0]
-        if any(a.shape[0] != n_words for a in arrays.values()):
-            raise ValueError("graph inputs must have equal length")
-        return arrays, n_words
+        planes = [feeds[n] for n in names]
+        n_words = _plane_words(planes, "graph inputs")
+        layout = mesh_mod.words_layout(self.words_mesh, n_words)
+        return dict(zip(names, self._words(planes, layout))), n_words, layout
 
     def _resolve_n_bits(self, n_bits, n_words):
         if n_bits is None:
@@ -725,7 +810,7 @@ class Lowered:
                 f"({(n_words - 1) * WORD_BITS}, {n_words * WORD_BITS}]")
         return n_bits
 
-    def _run_graph(self, arrays, n_words, n_bits, faults=None):
+    def _run_graph(self, arrays, n_words, layout, n_bits, faults=None):
         if not self.engine.device:
             self.schedule = self.cost(n_bits)
             return graph_ref_results(
@@ -740,12 +825,16 @@ class Lowered:
             outs, tiles, waves = self.engine.dispatch(
                 [arrays[n] for n in fp.loaded_inputs], fp.program,
                 fp.readback_rows, n_rows=fp.template_rows, geom=geom,
-                mesh=self.mesh, n_queues=self.n_queues, faults=faults)
+                mesh=self.mesh, n_queues=self.n_queues, faults=faults,
+                blocks=layout == "blocks")
             col = {row: i for i, row in enumerate(fp.readback_rows)}
             with telemetry.span("run.readback", cat="run", tid="run",
                                 outputs=len(fp.device_outputs)):
-                for name, row in fp.device_outputs:
-                    results[name] = outs[:, col[row]].reshape(-1)[:n_words]
+                planes = self._readback(
+                    outs, [col[row] for _, row in fp.device_outputs],
+                    n_words, layout)
+                for (name, _), plane in zip(fp.device_outputs, planes):
+                    results[name] = plane
         with telemetry.span("run.schedule", cat="run", tid="run"):
             sched = _make_fused_schedule(fp, n_bits, tiles, waves, geom)
             self.schedule = self.engine.lift_graph(self, sched)

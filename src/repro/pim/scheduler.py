@@ -484,36 +484,55 @@ def run_waves_baseline(staged: jax.Array, program: Sequence[AAP],
 
 
 @functools.lru_cache(maxsize=512)
-def _stager(n_arrays: int, n_words: int, lead: Tuple[int, ...], mesh):
+def _stager(n_arrays: int, n_words: int, lead: Tuple[int, ...], mesh,
+            blocks: bool = False):
     """Compiled staging kernel: pad + tile every operand in one fused
     dispatch, leaving the result device-resident (and shard-aligned on
     `mesh`) instead of round-tripping per-array pads through separate
-    eager kernels."""
+    eager kernels.
+
+    `blocks`: the operands arrive in the mesh's word layout
+    (`pim.mesh.WORDS_SPEC`, equal blocks) and each device pads and
+    tiles its own block into its own [waves, chips/mc, banks/mb,
+    subarrays, row_words] slots under `shard_map`: no collective."""
+    from repro.pim.mesh import AXIS_BANKS, AXIS_CHIPS, STAGED_SPEC, WORDS_SPEC
+    if blocks:
+        mc, mb = mesh.shape[AXIS_CHIPS], mesh.shape[AXIS_BANKS]
+        lead = (lead[0], lead[1] // mc, lead[2] // mb, lead[3], lead[4])
+        n_words //= mc * mb
     pad = lead[0] * lead[1] * lead[2] * lead[3] * lead[4] - n_words
 
     def impl(arrays: Tuple[jax.Array, ...]) -> jax.Array:
         return jnp.stack([jnp.pad(jnp.asarray(a, jnp.uint32), (0, pad))
                           .reshape(lead) for a in arrays], axis=1)
 
+    if blocks:
+        return jax.jit(jax.shard_map(
+            impl, mesh=mesh, in_specs=((WORDS_SPEC,) * n_arrays,),
+            out_specs=STAGED_SPEC, check_vma=False))
     shardings = None
     if mesh is not None:
-        from repro.pim.mesh import STAGED_SPEC
         shardings = NamedSharding(mesh, STAGED_SPEC)
     return jax.jit(impl, out_shardings=shardings)
 
 
 def stage_rows(arrays: Sequence[jax.Array], *, geom: DrimGeometry,
-               mesh=None) -> Tuple[jax.Array, int, int]:
+               mesh=None, blocks: bool = False
+               ) -> Tuple[jax.Array, int, int]:
     """Tile flat word arrays onto the fleet: pad to a whole number of
     waves and reshape to [waves, n_arrays, chips, banks, subarrays,
     row_words], device-resident (shard-aligned over `mesh` when given).
-    Returns (staged, tiles, waves)."""
+    `blocks=True` takes words in the mesh's block layout and maps lanes
+    block-major, each device staging its own block (`pim.mesh`); the
+    resident engine's mesh runs use it, the queued engine does not.
+    `tiles` and `waves` are the unsharded plan's either way.  Returns
+    (staged, tiles, waves)."""
     n_words = arrays[0].shape[0]
     row_w = geom.row_bits // WORD_BITS
     tiles = _ceil_div(n_words, row_w)
     waves = _ceil_div(tiles, geom.n_subarrays)
     lead = (waves, geom.chips, geom.banks, geom.subarrays_per_bank, row_w)
-    staged = _stager(len(arrays), n_words, lead, mesh)(tuple(arrays))
+    staged = _stager(len(arrays), n_words, lead, mesh, blocks)(tuple(arrays))
     return staged, tiles, waves
 
 

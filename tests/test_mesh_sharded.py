@@ -179,3 +179,246 @@ def test_forced_8device_cpu_mesh_subprocess(fast_mode):
     assert proc.returncode == 0, (
         f"forced-8-device suite failed:\n{proc.stdout}\n{proc.stderr}")
     assert "passed" in proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# The block layout of a mesh run's operand and result words
+# ---------------------------------------------------------------------------
+
+COLLECTIVES = ("all-gather", "all-to-all", "collective-permute",
+               "all-reduce", "reduce-scatter")
+
+
+def _layout_words(geom, mesh):
+    """Word counts that divide by the device count: two whole waves, a
+    ragged third wave, and less than a wave; then one that does not."""
+    d = mesh.devices.size
+    wave = geom.n_subarrays * geom.row_bits // 32
+    return [2 * wave, 2 * wave + 2 * d, 5 * d, 2 * wave + 3]
+
+
+@pytest.mark.parametrize("devices,n_words,want", [
+    (None, 8, "one"), (1, 8, "one"), (4, 8, "blocks"), (4, 10, "whole"),
+    (2, 10, "blocks")])
+def test_layout_decided_once_per_run(devices, n_words, want):
+    """The one layout decision (`pim.mesh.words_layout`), which reads
+    only the mesh's device count: one device runs the single-device
+    path, several take blocks where the words split evenly and whole
+    planes where they do not."""
+    from types import SimpleNamespace
+
+    from repro.pim.mesh import words_layout
+    mesh = (None if devices is None
+            else SimpleNamespace(devices=np.empty((1, devices))))
+    assert words_layout(mesh, n_words) == want
+
+
+def test_layout_words_mesh_is_the_resident_engines(small_geom):
+    """Only the resident engine lays a run's words over its mesh; the
+    queued engine stages them row-major from wherever they are."""
+    import drim
+    mesh = fleet_mesh(small_geom)
+    compiled = drim.compile("xnor2", geom=small_geom)
+    assert compiled.lower(mesh=mesh).words_mesh is mesh
+    assert compiled.lower(engine="queued", mesh=mesh).words_mesh is None
+    assert compiled.lower().words_mesh is None
+
+
+def _placed(planes, mesh):
+    from repro.pim.mesh import words_layout, words_sharding
+    if words_layout(mesh, planes[0].size) != "blocks":
+        return list(planes)
+    return [jax.device_put(p, words_sharding(mesh)) for p in planes]
+
+
+def _check_layout(out, mesh, n_words):
+    """Blocked results carry the layout, and each device's shard holds
+    its own block and no more."""
+    from repro.pim.mesh import words_layout, words_sharding
+    if words_layout(mesh, n_words) != "blocks":
+        return
+    per = n_words // mesh.devices.size
+    host = np.asarray(out)
+    assert out.sharding.is_equivalent_to(words_sharding(mesh), 1)
+    for shard in out.addressable_shards:
+        assert shard.data.shape == (per,)
+        lo = shard.index[0].start or 0
+        np.testing.assert_array_equal(np.asarray(shard.data),
+                                      host[lo:lo + per])
+
+
+@pytest.mark.parametrize("case", range(4))
+def test_layout_xnor2_block_in_block_out(small_geom, case):
+    """xnor2 over the mesh: block-sharded in, block-sharded out,
+    bit-identical to the single-device run and to numpy, with the
+    unsharded schedule."""
+    import drim
+    mesh = fleet_mesh(small_geom)
+    n_words = _layout_words(small_geom, mesh)[case]
+    a, b = random_operands("xnor2", n_words, seed=case)
+    low = drim.compile("xnor2", geom=small_geom).lower(mesh=mesh)
+    one = drim.compile("xnor2", geom=small_geom).lower()
+    (got,) = low.run(*_placed([a, b], mesh))
+    (want,) = one.run(a, b)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got), ~(a ^ b))
+    assert low.schedule == one.schedule
+    _check_layout(got, mesh, n_words)
+
+
+@pytest.mark.parametrize("case", range(4))
+def test_layout_bitlinear_graph_block_in_block_out(small_geom, case):
+    """The graph path (`bitlinear_kernel(32)`, 64 operand planes and a
+    constant zero plane) keeps the layout and the unsharded schedule."""
+    import drim
+    from repro.pim.bnn import bitlinear_kernel
+    traced = bitlinear_kernel(32).trace()
+    mesh = fleet_mesh(small_geom)
+    n_words = _layout_words(small_geom, mesh)[case]
+    rng = np.random.default_rng(0xB17 + case)
+    planes = [rng.integers(0, 1 << 32, n_words, dtype=np.uint32)
+              for _ in range(64)]
+    low = drim.compile(traced, geom=small_geom).lower(mesh=mesh)
+    one = drim.compile(traced, geom=small_geom).lower()
+    got = jax.tree.leaves(low.run(*_placed(planes, mesh)))
+    want = jax.tree.leaves(one.run(*planes))
+    ref = graph_ref_results(traced.graph, traced.feeds_for(planes))
+    assert len(got) == len(want) == len(ref) == 6
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        _check_layout(g, mesh, n_words)
+    assert sorted(np.asarray(g).tobytes() for g in got) == sorted(
+        np.asarray(r).tobytes() for r in ref.values())
+    assert low.schedule == one.schedule
+
+
+def test_layout_stager_and_readback_have_no_collectives(small_geom):
+    """The compiled stager, wave program and readback of a blocked mesh
+    run move no word between devices."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from repro.pim.mesh import STAGED_SPEC, _unstager, words_sharding
+    from repro.pim.scheduler import _stager, _wave_runner
+    mesh = fleet_mesh(small_geom)
+    d = mesh.devices.size
+    n_words = _layout_words(small_geom, mesh)[1]
+    row_w = small_geom.row_bits // 32
+    waves = -(-n_words // (small_geom.n_subarrays * row_w))
+    lead = (waves, small_geom.chips, small_geom.banks,
+            small_geom.subarrays_per_bank, row_w)
+    words = jax.ShapeDtypeStruct((n_words,), jnp.uint32,
+                                 sharding=words_sharding(mesh))
+    staged = jax.ShapeDtypeStruct((waves, 2) + lead[1:], jnp.uint32,
+                                  sharding=NamedSharding(mesh, STAGED_SPEC))
+    outs = jax.ShapeDtypeStruct((waves, 1) + lead[1:], jnp.uint32,
+                                sharding=NamedSharding(mesh, STAGED_SPEC))
+    texts = [
+        _stager(2, n_words, lead, mesh, True).lower((words, words)).compile(),
+        _wave_runner("resident", tuple(build_program("xnor2")), (2,), 16,
+                     mesh, False).lower(staged).compile(),
+        _unstager((0,), n_words // d, mesh).lower(outs).compile(),
+    ]
+    for compiled in texts:
+        hlo = compiled.as_text()
+        assert not [c for c in COLLECTIVES if c in hlo]
+
+
+@pytest.mark.parametrize("source", ["layout", "host", "one_device",
+                                    "replicated", "uneven"])
+def test_layout_cross_device_bytes(small_geom, source):
+    """run.xchip_bytes: nothing for operands in the layout, for host
+    operands (host-to-device bytes instead) and for replicated ones; the
+    other devices' blocks of a one-device operand; every operand and
+    result whole on every other device where the words do not split
+    evenly."""
+    import drim
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.pim.compiler import RUN_STATS
+    mesh = fleet_mesh(small_geom)
+    d = mesh.devices.size
+    words = _layout_words(small_geom, mesh)
+    n_words = words[3] if source == "uneven" else words[1]
+    a, b = random_operands("xnor2", n_words, seed=7)
+    nbytes = 4 * n_words
+    if source == "layout":
+        args, moved = _placed([a, b], mesh), 0
+    elif source == "one_device":
+        args = [jax.device_put(x, jax.devices()[0]) for x in (a, b)]
+        moved = 2 * nbytes * (d - 1) // d
+    elif source == "replicated":
+        whole = NamedSharding(mesh, PartitionSpec())
+        args, moved = [jax.device_put(x, whole) for x in (a, b)], 0
+    else:
+        args = [a, b]
+        moved = 3 * nbytes * (d - 1) if source == "uneven" else 0
+    h2d = 4 * sum(x.size for x in args if isinstance(x, np.ndarray))
+    low = drim.compile("xnor2", geom=small_geom).lower(mesh=mesh)
+    x0, h0 = RUN_STATS["xchip_bytes"], RUN_STATS["h2d_bytes"]
+    (got,) = low.run(*args)
+    np.testing.assert_array_equal(np.asarray(got), ~(a ^ b))
+    if d == 1:
+        moved = 0
+    assert RUN_STATS["xchip_bytes"] - x0 == moved
+    assert RUN_STATS["h2d_bytes"] - h0 == h2d
+
+
+def test_layout_one_by_one_mesh_is_the_plain_run(small_geom):
+    """A 1x1 mesh takes the plain path: same words, same schedule, the
+    same single-device result layout, nothing counted as crossing."""
+    import drim
+    from repro.pim.compiler import RUN_STATS
+    mesh = fleet_mesh(small_geom, devices=jax.devices()[:1])
+    assert mesh.devices.size == 1
+    n_words = 2 * small_geom.n_subarrays * small_geom.row_bits // 32 + 3
+    a, b = random_operands("xnor2", n_words, seed=11)
+    low = drim.compile("xnor2", geom=small_geom).lower(mesh=mesh)
+    plain = drim.compile("xnor2", geom=small_geom).lower()
+    x0 = RUN_STATS["xchip_bytes"]
+    (got,) = low.run(a, b)
+    (want,) = plain.run(a, b)
+    assert RUN_STATS["xchip_bytes"] == x0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert low.schedule == plain.schedule
+    assert got.sharding.device_set == want.sharding.device_set
+
+
+def test_layout_run_span_names_its_devices(small_geom):
+    """The `run` span says over how many devices the run went; placing
+    host operands on a blocked mesh is its own `run.place` span."""
+    import drim
+    from repro.pim.mesh import words_layout
+    from repro.runtime import telemetry
+    mesh = fleet_mesh(small_geom)
+    n_words = _layout_words(small_geom, mesh)[0]
+    a, b = random_operands("xnor2", n_words, seed=3)
+    low = drim.compile("xnor2", geom=small_geom).lower(mesh=mesh)
+    n0 = len(telemetry.trace_events())
+    with telemetry.armed():
+        low.run(a, b)
+    events = telemetry.trace_events()[n0:]
+    runs = [e for e in events if e.get("name") == "run"]
+    assert runs and runs[0]["args"]["devices"] == mesh.devices.size
+    placed = [e for e in events if e.get("name") == "run.place"]
+    assert len(placed) == int(words_layout(mesh, n_words) == "blocks")
+
+
+def test_forced_4device_layout_subprocess():
+    """The layout tests on a (1, 4) mesh, the `drim-s4` cell's: a fresh
+    interpreter whose CPU backend has four devices."""
+    if len(jax.devices()) >= 4:
+        pytest.skip("already running with forced multi-device platform")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", REPRO_FAST_TESTS="1",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    cmd = [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+           "-p", "no:xdist", os.path.abspath(__file__), "-k",
+           "layout and not subprocess"]
+    proc = subprocess.run(
+        cmd, env=env, cwd=os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))),
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, (
+        f"forced-4-device layout suite failed:\n{proc.stdout}\n"
+        f"{proc.stderr}")
+    assert "passed" in proc.stdout and "skipped" not in proc.stdout

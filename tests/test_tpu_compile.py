@@ -91,3 +91,38 @@ def test_flash_attention_compiles_at_prefill_shape(one_chip, direction):
     _compile(fn, one_chip, ((4, 12, 128, 64), jnp.bfloat16),
              ((4, 4, 128, 64), jnp.bfloat16),
              ((4, 4, 128, 64), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("stage", ["stager", "wave", "readback"])
+def test_drim_s4_mesh_path_compiles_without_collectives(topo, stage):
+    """The DRIM-S device over the (1, 4) mesh of a described 2x2 host,
+    64 banks a chip, 2^29-bit xnor2 operands in the mesh's word layout:
+    the stager, the wave program and the readback compile, and none
+    moves a word between chips."""
+    from jax.sharding import NamedSharding
+
+    from repro.core import DrimGeometry
+    from repro.pim.mesh import STAGED_SPEC, _unstager
+    from repro.pim.scheduler import _stager, _wave_runner, build_program
+    geom = DrimGeometry(banks=256, subarrays_per_bank=152)
+    mesh = drim.fleet_mesh(geom, devices=list(topo.devices))
+    assert dict(mesh.shape) == {"chips": 1, "banks": 4}
+    n_words, row_w, waves = 2**29 // 32, geom.row_bits // 32, 54
+    lead = (waves, geom.chips, geom.banks, geom.subarrays_per_bank, row_w)
+    staged = NamedSharding(mesh, STAGED_SPEC)
+    if stage == "stager":
+        w = jax.ShapeDtypeStruct((n_words,), jnp.uint32,
+                                 sharding=drim.words_sharding(mesh))
+        lowered = _stager(2, n_words, lead, mesh, True).lower((w, w))
+    elif stage == "wave":
+        x = jax.ShapeDtypeStruct((waves, 2) + lead[1:], jnp.uint32,
+                                 sharding=staged)
+        lowered = _wave_runner("resident", tuple(build_program("xnor2")),
+                               (2,), 16, mesh, False).lower(x)
+    else:
+        x = jax.ShapeDtypeStruct((waves, 1) + lead[1:], jnp.uint32,
+                                 sharding=staged)
+        lowered = _unstager((0,), n_words // 4, mesh).lower(x)
+    text = lowered.compile().as_text()
+    assert not [c for c in ("all-gather", "all-to-all", "collective-permute",
+                            "all-reduce", "reduce-scatter") if c in text]
