@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import AAP, DRIM_R, DrimGeometry, FaultModel
 from repro.core.subarray import N_XROWS, WORD_BITS
@@ -50,12 +51,15 @@ from repro.runtime import telemetry
 
 # Always-on counters at the run and lowering boundaries (registry
 # namespaces, like `LOWER_CACHE_STATS`): "run.calls" per `Lowered.run`,
-# "run.h2d_bytes" for the uint32 words of every host (numpy) operand or
-# constant plane a run turns into a device array, "run.xchip_bytes" for
-# the operand and result bytes a run over a mesh of several devices
-# delivers to a device other than the one that holds or simulates them,
-# and "lower.us" for the integer microseconds spent in
-# `Compiled.lower`, every pass included.
+# "run.h2d_bytes" for the uint32 words of the host (numpy) operand
+# planes a run sends to the device and "run.h2d_transfers" for the
+# transfers that carry them (one a run at most: the planes go stacked),
+# "run.programs" for the compiled programs a run launches (booked where
+# each is launched: `scheduler.RUN_STATS` is this namespace),
+# "run.xchip_bytes" for the operand and result bytes a run over a mesh
+# of several devices delivers to a device other than the one that holds
+# or simulates them, and "lower.us" for the integer microseconds spent
+# in `Compiled.lower`, every pass included.
 RUN_STATS = telemetry.REGISTRY.counters("run")
 LOWER_STATS = telemetry.REGISTRY.counters("lower")
 
@@ -86,18 +90,22 @@ class Engine:
     numbers lift into this engine's cost model.
 
     `dispatch(arrays, program, result_rows, n_rows=, geom=, mesh=,
-    n_queues=, faults=, blocks=) -> (outs, tiles, waves)` runs one
-    uniform program over the staged payload (`blocks`: the words are in
-    the mesh's block layout, `pim.mesh.words_layout`); `lift_op` /
-    `lift_graph` wrap measured (or closed-form) tiling into the
-    engine's Schedule flavour.  `device` is False for comparator
-    engines (TPU roofline) that never touch the simulated fleet.
+    n_queues=, faults=, blocks=) -> (outs, tiles, waves)` stages the
+    flat word arrays and runs one uniform program over the payload
+    (`blocks`: the words are in the mesh's block layout,
+    `pim.mesh.words_layout`), step by step; `lift_op` / `lift_graph`
+    wrap measured (or closed-form) tiling into the engine's Schedule
+    flavour.  `fused` engines run a whole `Lowered.run` as one compiled
+    program instead (`scheduler.run_program`).  `device` is False for
+    comparator engines (TPU roofline) that never touch the simulated
+    fleet.
     """
 
     name: str
     description: str
     device: bool = True
     dispatch: Optional[Callable] = None
+    fused: bool = False
     lift_op: Optional[Callable] = None
     lift_graph: Optional[Callable] = None
 
@@ -143,17 +151,12 @@ def _simd_dispatch(engine_name: str) -> Callable:
     def dispatch(arrays, program, result_rows, *, n_rows, geom,
                  mesh=None, n_queues=None, faults=None, blocks=False):
         from repro.pim.scheduler import run_waves, stage_rows
-        with telemetry.span("run.stage", cat="run", tid="run",
-                            engine=engine_name):
-            staged, tiles, waves = stage_rows(
-                arrays, geom=geom,
-                mesh=mesh if engine_name == "resident" else None,
-                blocks=blocks)
-        with telemetry.span("run.dispatch", cat="run", tid="run",
-                            engine=engine_name, waves=waves, tiles=tiles,
-                            aaps=len(program)):
-            outs = run_waves(staged, program, result_rows, n_rows=n_rows,
-                             mesh=mesh, engine=engine_name, faults=faults)
+        staged, tiles, waves = stage_rows(
+            arrays, geom=geom,
+            mesh=mesh if engine_name == "resident" else None,
+            blocks=blocks)
+        outs = run_waves(staged, program, result_rows, n_rows=n_rows,
+                         mesh=mesh, engine=engine_name, faults=faults)
         return outs, tiles, waves
     return dispatch
 
@@ -214,12 +217,12 @@ ENGINE_REGISTRY.register(Engine(
     "resident", "trace-time-unrolled program over device-resident "
     "tiles, donated buffers, optional shard_map over a fleet mesh",
     dispatch=_simd_dispatch("resident"), lift_op=_lift_op_plain,
-    lift_graph=_lift_graph_plain))
+    lift_graph=_lift_graph_plain, fused=True))
 ENGINE_REGISTRY.register(Engine(
     "baseline", "PR 2 reference: full device state through the vmapped "
     "lax.scan interpreter, fresh state per wave",
     dispatch=_simd_dispatch("baseline"), lift_op=_lift_op_plain,
-    lift_graph=_lift_graph_plain))
+    lift_graph=_lift_graph_plain, fused=True))
 ENGINE_REGISTRY.register(Engine(
     "queued", "per-bank command queues with independent program "
     "counters, contention + DMA-overlap cost model",
@@ -230,7 +233,7 @@ ENGINE_REGISTRY.register(Engine(
     "data, replayed by an on-device program counter over VMEM-resident "
     "row planes (interpret mode off-TPU)",
     dispatch=_pallas_dispatch, lift_op=_lift_op_plain,
-    lift_graph=_lift_graph_plain))
+    lift_graph=_lift_graph_plain, fused=True))
 ENGINE_REGISTRY.register(Engine(
     "tpu", "roofline comparator: numpy oracle semantics, TPU v5e "
     "HBM/VPU cost model — the offload verdict's contender",
@@ -526,41 +529,25 @@ class EccReport:
         return self.mismatch_bits > 0
 
 
-def _device_words(x) -> jax.Array:
-    """One operand plane as flat uint32 device words; a numpy array
-    books its words in "run.h2d_bytes" (a device array moves nothing)."""
-    if isinstance(x, np.ndarray):
-        RUN_STATS["h2d_bytes"] += x.size * 4
-    return jnp.asarray(x, jnp.uint32).reshape(-1)
-
-
 def _plane_words(planes, what: str) -> int:
     """The common word count of a run's operand planes."""
     sizes = {int(np.size(x)) for x in planes}
-    if len(sizes) != 1:
+    if len(sizes) > 1:
         raise ValueError(f"{what} must have equal length")
-    return sizes.pop()
+    return sizes.pop() if sizes else 1
 
 
 def _mesh_words(planes, mesh, layout: str) -> list:
-    """Operand planes of a resident run over a mesh of several devices,
+    """Device planes of a resident run over a mesh of several devices,
     as flat uint32 words in `layout` ("blocks" or "whole",
     `pim.mesh.words_layout`).  Planes held otherwise are placed once
-    (span "run.place" for the blocks); the bytes that reach a device
-    other than the one holding them go to "run.xchip_bytes".  The whole
-    path places every operand whole on every device, and that is what
-    is counted."""
-    if layout == "whole":
-        whole = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-        ops = [_device_words(x) for x in planes]
-        RUN_STATS["xchip_bytes"] += sum(mesh_mod.missing_bytes(o, whole)
-                                        for o in ops)
-        return [jax.device_put(o, whole) for o in ops]
-    target = mesh_mod.words_sharding(mesh)
+    (span "run.place"); the bytes that reach a device other than the
+    one holding them go to "run.xchip_bytes"."""
+    target = (mesh_mod.words_sharding(mesh) if layout == "blocks"
+              else NamedSharding(mesh, P()))
 
     def in_layout(x) -> bool:
-        return (isinstance(x, jax.Array) and x.ndim == 1
-                and x.dtype == jnp.uint32
+        return (x.ndim == 1 and x.dtype == jnp.uint32
                 and x.sharding.is_equivalent_to(target, 1))
 
     ops, moved = list(planes), 0
@@ -569,15 +556,58 @@ def _mesh_words(planes, mesh, layout: str) -> list:
         with telemetry.span("run.place", cat="run", tid="run",
                             planes=len(foreign)):
             for i in foreign:
-                if isinstance(ops[i], jax.Array):
-                    w = jnp.asarray(ops[i], jnp.uint32).reshape(-1)
-                    moved += mesh_mod.missing_bytes(w, target)
-                else:
-                    w = np.asarray(ops[i], np.uint32).reshape(-1)
-                    RUN_STATS["h2d_bytes"] += w.size * 4
+                w = jnp.asarray(ops[i], jnp.uint32).reshape(-1)
+                moved += mesh_mod.missing_bytes(w, target)
                 ops[i] = jax.device_put(w, target)
     RUN_STATS["xchip_bytes"] += moved
     return ops
+
+
+def _send(host, n_words: int, mesh, layout: str) -> jax.Array:
+    """A run's host planes stacked into one [n_host, n_words] uint32
+    block and sent in one transfer, in the run's `layout`: on the
+    default device ("one"), in the mesh's block layout ("blocks", span
+    "run.place") or whole on every device ("whole", whose copies beyond
+    the first count in "run.xchip_bytes")."""
+    block = np.empty((len(host), n_words), np.uint32)
+    for j, x in enumerate(host):
+        block[j] = np.asarray(x).reshape(-1)
+    RUN_STATS["h2d_bytes"] += block.nbytes
+    if layout == "one":
+        return jax.device_put(block)
+    if layout == "whole":
+        RUN_STATS["xchip_bytes"] += ((mesh_mod.n_devices(mesh) - 1)
+                                     * block.nbytes)
+        return jax.device_put(block, NamedSharding(mesh, P()))
+    with telemetry.span("run.place", cat="run", tid="run",
+                        planes=len(host)):
+        return jax.device_put(block, NamedSharding(
+            mesh, P(None, *mesh_mod.WORDS_SPEC)))
+
+
+@dataclasses.dataclass(frozen=True)
+class _Feeds:
+    """A run's operand planes after its one feed step: `srcs[k]` names
+    where plane k is, as `scheduler.run_program` reads it: ("d", i)
+    device plane `dev[i]`, ("h", j) row j of the stacked host block
+    `host[0]`, ("z", 0) a constant zero plane, made on the device."""
+
+    n_words: int
+    layout: str
+    srcs: Tuple[Tuple[str, int], ...]
+    dev: Tuple[jax.Array, ...]
+    host: Tuple[jax.Array, ...]
+
+    def plane(self, src) -> jax.Array:
+        """One plane as flat device words, for the step-by-step
+        engines."""
+        kind, i = src
+        if kind == "d":
+            x = self.dev[i]
+            return x if x.ndim == 1 else x.reshape(-1)
+        if kind == "h":
+            return self.host[0][i]
+        return jnp.zeros(self.n_words, jnp.uint32)
 
 
 class Lowered:
@@ -674,6 +704,13 @@ class Lowered:
         lowering-time default).  With `harden="ecc"` lowerings the
         detection evidence of each run lands on `self.last_ecc`.
 
+        Device planes (`jax.Array`) pass through as they are; host
+        planes are stacked and sent in one transfer; a traced program's
+        constant planes are made on the device.  On the resident,
+        baseline and pallas engines the run is then one compiled
+        program that stages, runs the waves and cuts the result planes
+        out (`scheduler.run_program`).
+
         Over a fleet mesh of several devices (resident engine), operand
         and result words take the mesh's block layout
         (`pim.mesh.words_sharding`): each device holds, simulates and
@@ -698,32 +735,118 @@ class Lowered:
         faults = self._resolve_faults(faults)
         if self.kind == "op":
             return self._run_op(args, n_bits, faults)
+        names = self.graph.input_names
         with telemetry.span("run.feeds", cat="run", tid="run"):
-            if self.traced is not None and not (
-                    len(args) == 1 and isinstance(args[0], dict)):
-                feeds = self.traced.feeds_for(args)
-            elif len(args) == 1 and isinstance(args[0], dict):
-                feeds = dict(args[0])
-                if self.traced is not None:
-                    for cname in self.traced.const_names:
-                        if cname not in feeds:
-                            n_words = int(np.prod(np.shape(
-                                next(iter(feeds.values())))))
-                            feeds[cname] = np.zeros(n_words, np.uint32)
-            else:
-                raise ValueError("graph lowering expects a feeds dict (or "
-                                 "positional planes for traced programs)")
-            arrays, n_words, layout = self._check_feeds(feeds)
-            n_bits = self._resolve_n_bits(n_bits, n_words)
-        outs = (self._run_partitioned(arrays, n_bits, faults)
-                if self.kind == "partition"
-                else self._run_graph(arrays, n_words, layout, n_bits,
-                                     faults))
+            planes = self._graph_planes(args)
+            if not self.engine.device:
+                n_words = _plane_words(
+                    [x for x in planes if x is not None], "graph inputs")
+                self.schedule = self.cost(
+                    self._resolve_n_bits(n_bits, n_words))
+                return self._finish(graph_ref_results(self.graph, {
+                    n: (np.zeros(n_words, np.uint32) if x is None else
+                        np.asarray(x, np.uint32).reshape(-1))
+                    for n, x in zip(names, planes)}))
+            feeds = self._feed(planes, "graph inputs")
+            n_bits = self._resolve_n_bits(n_bits, feeds.n_words)
+        if self.kind == "partition":
+            return self._finish(self._run_partitioned(
+                {n: feeds.plane(src) for n, src in zip(names, feeds.srcs)},
+                n_bits, faults))
+        return self._finish(self._run_graph(feeds, n_bits, faults))
+
+    def _finish(self, outs):
         if self.harden is not None and "ecc" in self.harden:
             outs = self._check_ecc(dict(outs))
         if self.traced is not None:
             return self.traced.restructure(outs)
         return outs
+
+    def _graph_planes(self, args) -> list:
+        """A graph run's arguments as its input planes in
+        `graph.input_names` order, None for a traced program's constant
+        plane that the caller did not give."""
+        if len(args) == 1 and isinstance(args[0], dict):
+            feeds = dict(args[0])
+        elif self.traced is not None:
+            feeds = self.traced.arg_feeds(args)
+        else:
+            raise ValueError("graph lowering expects a feeds dict (or "
+                             "positional planes for traced programs)")
+        names = self.graph.input_names
+        consts = self.traced.const_names if self.traced is not None else ()
+        missing = set(names) - set(feeds) - set(consts)
+        extra = set(feeds) - set(names)
+        if missing or extra:
+            raise ValueError(f"feed mismatch: missing {sorted(missing)}, "
+                             f"unexpected {sorted(extra)}")
+        return [feeds.get(n) for n in names]
+
+    def _feed(self, planes, what: str) -> _Feeds:
+        """The one feed step of a run over `planes` (None: a constant
+        zero plane, made on the device).  Device planes pass through
+        (placed once where a mesh run needs another layout); host planes
+        are stacked and sent in one transfer (`_send`)."""
+        n_words = _plane_words([x for x in planes if x is not None], what)
+        layout = mesh_mod.words_layout(self.words_mesh, n_words)
+        srcs, dev, host = [], [], []
+        for x in planes:
+            if x is None:
+                srcs.append(("z", 0))
+            elif isinstance(x, jax.Array):
+                srcs.append(("d", len(dev)))
+                dev.append(x)
+            else:
+                srcs.append(("h", len(host)))
+                host.append(x)
+        if layout != "one":
+            dev = _mesh_words(dev, self.words_mesh, layout)
+        block = ()
+        if host:
+            block = (_send(host, n_words, self.words_mesh, layout),)
+        RUN_STATS["h2d_transfers"] += len(block)
+        return _Feeds(n_words, layout, tuple(srcs), tuple(dev), block)
+
+    def _execute(self, feeds: _Feeds, staged, outputs, program,
+                 result_rows, n_rows, faults):
+        """Run `program` over the planes `staged` (sources of `feeds`)
+        and return the planes `outputs` (("col", c): readback column c;
+        a source: that input plane as it is) with the run's tiles and
+        waves.  A fused engine launches one compiled program
+        (`scheduler.run_program`); the queued engine stages, dispatches
+        and reads back step by step."""
+        from repro.pim.scheduler import read_planes, run_program, tiling
+        n_words = feeds.n_words
+        tiles, waves, lead = tiling(n_words, self.geom)
+        cols = [i for kind, i in outputs if kind == "col"]
+        if feeds.layout == "whole":
+            d = mesh_mod.n_devices(self.words_mesh)
+            RUN_STATS["xchip_bytes"] += (d - 1) * n_words * 4 * len(cols)
+        attrs = dict(cat="run", tid="run", engine=self.engine.name,
+                     waves=waves, tiles=tiles, aaps=len(program))
+        if self.engine.fused:
+            fn = run_program(
+                self.engine.name, program, result_rows, n_rows, staged,
+                outputs, n_words, lead,
+                self.words_mesh if feeds.layout != "one" else None,
+                feeds.layout == "blocks",
+                faults.wave_model() if faults is not None else None)
+            with telemetry.span("run.dispatch", **attrs):
+                out = fn(feeds.dev, feeds.host)
+            RUN_STATS["programs"] += 1
+            return out, tiles, waves
+        read = {}
+        if cols:
+            with telemetry.span("run.dispatch", **attrs):
+                outs, tiles, waves = self.engine.dispatch(
+                    [feeds.plane(src) for src in staged], program,
+                    result_rows, n_rows=n_rows, geom=self.geom,
+                    mesh=self.mesh, n_queues=self.n_queues, faults=faults)
+            with telemetry.span("run.readback", cat="run", tid="run",
+                                outputs=len(cols)):
+                read = dict(zip(cols, read_planes(outs, cols, n_words)))
+        return tuple(read[i] if kind == "col" else feeds.plane((kind, i))
+                     for kind, i in outputs), tiles, waves
 
     def _run_op(self, operands, n_bits, faults=None):
         arity = OP_ARITY[self.op]
@@ -743,59 +866,20 @@ class Lowered:
             self.schedule = self.cost(n_bits)
             return expected_results(self.op, args)
         with telemetry.span("run.feeds", cat="run", tid="run"):
-            n_words = _plane_words(operands, "operands")
-            layout = mesh_mod.words_layout(self.words_mesh, n_words)
-            ops = self._words(operands, layout)
+            feeds = self._feed(list(operands), "operands")
+            n_words = feeds.n_words
             if n_bits is None:
                 n_bits = n_words * WORD_BITS
             if not 0 < n_bits <= n_words * WORD_BITS:
                 raise ValueError(
                     "n_bits out of range for the given operands")
-        outs, tiles, waves = self.engine.dispatch(
-            ops, self.program, self.result_rows, n_rows=self.n_rows,
-            geom=self.geom, mesh=self.mesh, n_queues=self.n_queues,
-            faults=faults, blocks=layout == "blocks")
-        with telemetry.span("run.readback", cat="run", tid="run",
-                            op=self.op):
-            results = self._readback(outs, range(len(self.result_rows)),
-                                     n_words, layout)
+        results, tiles, waves = self._execute(
+            feeds, feeds.srcs,
+            tuple(("col", i) for i in range(len(self.result_rows))),
+            self.program, self.result_rows, self.n_rows, faults)
         with telemetry.span("run.schedule", cat="run", tid="run"):
             self.schedule = self.engine.lift_op(self, n_bits, tiles, waves)
         return results
-
-    def _words(self, planes, layout: str) -> list:
-        """Operand planes as flat uint32 device words in the run's
-        `layout` (`pim.mesh.words_layout`)."""
-        if layout == "one":
-            return [_device_words(x) for x in planes]
-        return _mesh_words(planes, self.words_mesh, layout)
-
-    def _readback(self, outs, cols, n_words,
-                  layout: str) -> Tuple[jax.Array, ...]:
-        """Result planes `cols` of a dispatch's readback block as flat
-        words in the run's `layout`: "blocks" each device trimming its
-        own slab (`pim.mesh.read_blocks`), "whole" every result whole on
-        every device."""
-        if layout == "blocks":
-            return mesh_mod.read_blocks(outs, cols, n_words,
-                                        self.words_mesh)
-        if layout == "whole":
-            d = mesh_mod.n_devices(self.words_mesh)
-            RUN_STATS["xchip_bytes"] += (d - 1) * n_words * 4 * len(cols)
-        return tuple(outs[:, c].reshape(-1)[:n_words] for c in cols)
-
-    def _check_feeds(self, feeds
-                     ) -> Tuple[Dict[str, jax.Array], int, str]:
-        names = self.graph.input_names
-        missing = set(names) - set(feeds)
-        extra = set(feeds) - set(names)
-        if missing or extra:
-            raise ValueError(f"feed mismatch: missing {sorted(missing)}, "
-                             f"unexpected {sorted(extra)}")
-        planes = [feeds[n] for n in names]
-        n_words = _plane_words(planes, "graph inputs")
-        layout = mesh_mod.words_layout(self.words_mesh, n_words)
-        return dict(zip(names, self._words(planes, layout))), n_words, layout
 
     def _resolve_n_bits(self, n_bits, n_words):
         if n_bits is None:
@@ -810,35 +894,22 @@ class Lowered:
                 f"({(n_words - 1) * WORD_BITS}, {n_words * WORD_BITS}]")
         return n_bits
 
-    def _run_graph(self, arrays, n_words, layout, n_bits, faults=None):
-        if not self.engine.device:
-            self.schedule = self.cost(n_bits)
-            return graph_ref_results(
-                self.graph, {n: np.asarray(a) for n, a in arrays.items()})
-        fp, geom = self.fp, self.geom
-        tiles = _ceil_div(n_bits, geom.row_bits)
-        waves = _ceil_div(tiles, geom.n_subarrays)
-        results = {name: arrays[src] for name, src in fp.alias_outputs}
-        if fp.device_outputs:
-            # ceil(ceil(n_bits/32) / (row_bits/32)) == ceil(n_bits/
-            # row_bits): word-tiled staging agrees with the bit plan.
-            outs, tiles, waves = self.engine.dispatch(
-                [arrays[n] for n in fp.loaded_inputs], fp.program,
-                fp.readback_rows, n_rows=fp.template_rows, geom=geom,
-                mesh=self.mesh, n_queues=self.n_queues, faults=faults,
-                blocks=layout == "blocks")
-            col = {row: i for i, row in enumerate(fp.readback_rows)}
-            with telemetry.span("run.readback", cat="run", tid="run",
-                                outputs=len(fp.device_outputs)):
-                planes = self._readback(
-                    outs, [col[row] for _, row in fp.device_outputs],
-                    n_words, layout)
-                for (name, _), plane in zip(fp.device_outputs, planes):
-                    results[name] = plane
+    def _run_graph(self, feeds: _Feeds, n_bits, faults=None):
+        fp = self.fp
+        src = dict(zip(self.graph.input_names, feeds.srcs))
+        col = {row: i for i, row in enumerate(fp.readback_rows)}
+        names = ([name for name, _ in fp.alias_outputs]
+                 + [name for name, _ in fp.device_outputs])
+        outputs = (tuple(src[a] for _, a in fp.alias_outputs)
+                   + tuple(("col", col[row]) for _, row in fp.device_outputs))
+        planes, tiles, waves = self._execute(
+            feeds, tuple(src[n] for n in fp.loaded_inputs), outputs,
+            fp.program, fp.readback_rows, fp.template_rows, faults)
         with telemetry.span("run.schedule", cat="run", tid="run"):
-            sched = _make_fused_schedule(fp, n_bits, tiles, waves, geom)
+            sched = _make_fused_schedule(fp, n_bits, tiles, waves,
+                                         self.geom)
             self.schedule = self.engine.lift_graph(self, sched)
-        return results
+        return dict(zip(names, planes))
 
     def _run_partitioned(self, arrays, n_bits, faults=None):
         from repro.pim.queue import _execute_partitioned

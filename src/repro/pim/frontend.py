@@ -285,9 +285,9 @@ class TracedProgram:
     def n_nodes(self) -> int:
         return len(self.graph.nodes)
 
-    def feeds_for(self, arrays: Sequence[Any]) -> Dict[str, Any]:
-        """Map positional word arrays onto the graph's named inputs and
-        append the constant planes.  Raises TraceError on non-integer
+    def arg_feeds(self, arrays: Sequence[Any]) -> Dict[str, Any]:
+        """Map positional word arrays onto the graph's named inputs,
+        without the constant planes.  Raises TraceError on non-integer
         dtypes (a float feed silently truncating would be a silent
         wrong answer) and ValueError on arity mismatch; per-feed length
         agreement is enforced downstream by the executor."""
@@ -296,7 +296,6 @@ class TracedProgram:
                 f"{self.name} takes {len(self.arg_names)} input planes "
                 f"({', '.join(self.arg_names)}), got {len(arrays)}")
         feeds: Dict[str, Any] = {}
-        n_words = None
         for name, a in zip(self.arg_names, arrays):
             dt = getattr(a, "dtype", None)
             if dt is None:
@@ -307,8 +306,13 @@ class TracedProgram:
                     f"input {name!r} has dtype {dt}, expected packed "
                     f"integer words (uint32 bit-planes)")
             feeds[name] = a
-            if n_words is None:
-                n_words = int(np.prod(getattr(a, "shape", (len(a),))))
+        return feeds
+
+    def feeds_for(self, arrays: Sequence[Any]) -> Dict[str, Any]:
+        """`arg_feeds` with the constant planes appended as host zeros
+        (`Lowered.run` makes them on the device instead)."""
+        feeds = self.arg_feeds(arrays)
+        n_words = int(np.size(arrays[0])) if len(arrays) else 1
         for cname in self.const_names:
             feeds[cname] = np.zeros(n_words or 1, np.uint32)
         return feeds

@@ -179,8 +179,10 @@ def shard_device(dev: DrimDevice, mesh: Mesh) -> DrimDevice:
 
 @functools.lru_cache(maxsize=256)
 def _unstager(cols: Tuple[int, ...], per: int, mesh: Mesh):
+    from repro.pim.scheduler import read_planes
+
     def unstage(outs: jax.Array) -> Tuple[jax.Array, ...]:
-        return tuple(outs[:, c].reshape(-1)[:per] for c in cols)
+        return read_planes(outs, cols, per)
 
     return jax.jit(jax.shard_map(
         unstage, mesh=mesh, in_specs=(STAGED_SPEC,),
@@ -193,4 +195,6 @@ def read_blocks(outs: jax.Array, cols: Sequence[int], n_words: int,
     [waves, rows, chips, banks, subarrays, row_words]: each device's
     own slab flattened and trimmed to its block, returned in the
     `words_sharding` layout with no collective."""
+    from repro.pim.scheduler import RUN_STATS
+    RUN_STATS["programs"] += 1
     return _unstager(tuple(cols), n_words // n_devices(mesh), mesh)(outs)

@@ -45,14 +45,14 @@ from jax.sharding import NamedSharding
 
 from repro.core import (AAP, CMDS_PER_AAP, DRIM_R, DrimGeometry,
                         FaultModel, simulate_bus_issue)
-from repro.core.subarray import WORD_BITS
 from repro.core.timing import CMD_SLOTS_PER_AAP, ddr_rows_s
 from repro.pim.graph import (DEFAULT_ROW_BUDGET, BulkGraph, FusedSchedule,
                              GraphPartition, partition_graph)
 from repro.pim.mesh import STAGED_SPEC, fleet_mesh
 from repro.pim.scheduler import (N_DATA_ROWS, OP_ARITY, RESULT_ROWS,
-                                 TRACE_COUNTS, _ceil_div, encoded_program,
-                                 stage_rows, wave_fn)
+                                 RUN_STATS, TRACE_COUNTS, _ceil_div,
+                                 encoded_program, stage_rows, tiling,
+                                 wave_fn)
 from repro.runtime import telemetry
 
 # A queue per bank is the hardware concept, but a 256-bank DRIM-S sweep
@@ -142,12 +142,10 @@ def stage_rows_queued(arrays: Sequence[jax.Array], *, geom: DrimGeometry,
     Same tile -> slot order as `scheduler.stage_rows` by construction.
     Returns (staged_per_queue, tiles, waves)."""
     n_words = arrays[0].shape[0]
-    row_w = geom.row_bits // WORD_BITS
-    tiles = _ceil_div(n_words, row_w)
-    waves = _ceil_div(tiles, geom.n_subarrays)
-    lead = (waves, geom.chips, geom.banks, geom.subarrays_per_bank, row_w)
+    tiles, waves, lead = tiling(n_words, geom)
     staged_qs = _queued_stager(len(arrays), n_words, lead, n_queues,
                                mesh)(tuple(arrays))
+    RUN_STATS["programs"] += 1
     return staged_qs, tiles, waves
 
 
@@ -250,6 +248,7 @@ def run_waves_queued(staged_qs: Sequence[jax.Array],
     runner = _queued_runner(progs, tuple(tuple(r) for r in result_rows),
                             tuple(n_rows), mesh, donate, body_engine,
                             faults, bank_geoms)
+    RUN_STATS["programs"] += 1
     if timings is not None:
         t0 = time.perf_counter()
         compiled = runner.lower(*staged_qs).compile()

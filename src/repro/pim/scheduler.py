@@ -50,7 +50,7 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (AAP, DRIM_R, DrimGeometry, encode,
                         make_subarray, microprogram_add, microprogram_copy,
@@ -308,6 +308,11 @@ def plan_schedule(op: str, n_bits: int, *,
 # registry's "wave_trace" namespace (same Counter object).
 TRACE_COUNTS: collections.Counter = \
     telemetry.REGISTRY.counters("wave_trace")
+# "run.programs": compiled programs launched on the device for a run
+# (the stager, the wave program, a fused `run_program`), booked where
+# each is launched; eager ops are not counted.  The registry's "run"
+# namespace, as `compiler.RUN_STATS`.
+RUN_STATS = telemetry.REGISTRY.counters("run")
 
 ENGINES = ("resident", "baseline", "queued", "pallas")
 
@@ -394,20 +399,15 @@ def wave_fn(engine: str, program: Tuple[AAP, ...],
     return one_wave
 
 
-@functools.lru_cache(maxsize=512)
-def _wave_runner(engine: str, program: Tuple[AAP, ...],
-                 result_rows: Tuple[int, ...], n_rows: int, mesh,
-                 donate: bool, faults=None, bank_geom=None):
-    """Compiled wave executor for one (engine, program, readback, mesh,
-    faults) signature: a single `lax.map` of the shared `wave_fn` body
-    over the wave axis.  With a mesh, the body runs under `shard_map`
-    over (chips, banks) with no collectives; `donate=True` hands the
-    staged buffer to XLA for output reuse.  A `FaultModel` is frozen/
-    hashable, so faulted builds cache alongside the clean ones."""
+def _waves(engine: str, program: Tuple[AAP, ...],
+           result_rows: Tuple[int, ...], n_rows: int, faults=None,
+           bank_geom=None):
+    """The wave loop as a traceable function of the staged payload: a
+    single `lax.map` of the shared `wave_fn` body over the wave axis.
+    Called once per compiled build (`_wave_runner`, `run_program`),
+    which books the armed fault-site census: how many DRA/TRA instances
+    of this program can draw flips on this engine."""
     if faults is not None:
-        # Armed fault-site census, booked once per build (lru_cached
-        # like the trace counts): how many DRA/TRA instances of this
-        # program can draw flips on this engine.
         for kind, n in faults.count_faultable(program).items():
             if n:
                 telemetry.REGISTRY.counters("faults")[
@@ -419,11 +419,22 @@ def _wave_runner(engine: str, program: Tuple[AAP, ...],
         return jax.lax.map(wave_fn(engine, program, result_rows, n_rows,
                                    faults, bank_geom),
                            staged)
+    return body
 
-    fn = body
+
+@functools.lru_cache(maxsize=512)
+def _wave_runner(engine: str, program: Tuple[AAP, ...],
+                 result_rows: Tuple[int, ...], n_rows: int, mesh,
+                 donate: bool, faults=None, bank_geom=None):
+    """Compiled wave executor for one (engine, program, readback, mesh,
+    faults) signature: the `_waves` loop.  With a mesh, it runs under
+    `shard_map` over (chips, banks) with no collectives; `donate=True`
+    hands the staged buffer to XLA for output reuse.  A `FaultModel` is
+    frozen/hashable, so faulted builds cache alongside the clean ones."""
+    fn = _waves(engine, program, result_rows, n_rows, faults, bank_geom)
     if mesh is not None:
         from repro.pim.mesh import STAGED_SPEC
-        fn = jax.shard_map(body, mesh=mesh, in_specs=(STAGED_SPEC,),
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(STAGED_SPEC,),
                            out_specs=STAGED_SPEC, check_vma=False)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
@@ -468,6 +479,7 @@ def run_waves(staged: jax.Array, program: Sequence[AAP],
         mesh = None
     runner = _wave_runner(engine, tuple(program), tuple(result_rows),
                           n_rows, mesh, donate, faults, bank_geom)
+    RUN_STATS["programs"] += 1
     return runner(staged)
 
 
@@ -483,6 +495,45 @@ def run_waves_baseline(staged: jax.Array, program: Sequence[AAP],
                      engine="baseline")
 
 
+def tiling(n_words: int, geom: DrimGeometry) -> Tuple[int, int, Tuple]:
+    """(tiles, waves, lead) of `n_words` flat words on the fleet: tiles
+    of one row each, waves of `n_subarrays` tiles, and the staged
+    payload's [waves, chips, banks, subarrays, row_words] shape."""
+    row_w = geom.row_bits // WORD_BITS
+    tiles = _ceil_div(n_words, row_w)
+    waves = _ceil_div(tiles, geom.n_subarrays)
+    return tiles, waves, (waves, geom.chips, geom.banks,
+                          geom.subarrays_per_bank, row_w)
+
+
+def _block(n_words: int, lead: Tuple[int, ...], mesh) -> Tuple[int, Tuple]:
+    """One device's words and staged lead in the mesh's block layout
+    (`pim.mesh.WORDS_SPEC`): an equal share of the words over that
+    device's [waves, chips/mc, banks/mb, subarrays, row_words] slots."""
+    from repro.pim.mesh import AXIS_BANKS, AXIS_CHIPS
+    mc, mb = mesh.shape[AXIS_CHIPS], mesh.shape[AXIS_BANKS]
+    return (n_words // (mc * mb),
+            (lead[0], lead[1] // mc, lead[2] // mb, lead[3], lead[4]))
+
+
+def _stage(n_words: int, lead: Tuple[int, ...]):
+    """The staging step as a traceable function: pad every flat word
+    array to the payload and tile it, stacked on axis 1."""
+    pad = lead[0] * lead[1] * lead[2] * lead[3] * lead[4] - n_words
+
+    def impl(arrays: Tuple[jax.Array, ...]) -> jax.Array:
+        return jnp.stack([jnp.pad(jnp.asarray(a, jnp.uint32), (0, pad))
+                          .reshape(lead) for a in arrays], axis=1)
+    return impl
+
+
+def read_planes(outs: jax.Array, cols: Sequence[int],
+                n_words: int) -> Tuple[jax.Array, ...]:
+    """Result planes `cols` of a readback block [waves, rows, chips,
+    banks, subarrays, row_words] as flat words, trimmed to `n_words`."""
+    return tuple(outs[:, c].reshape(-1)[:n_words] for c in cols)
+
+
 @functools.lru_cache(maxsize=512)
 def _stager(n_arrays: int, n_words: int, lead: Tuple[int, ...], mesh,
             blocks: bool = False):
@@ -493,27 +544,18 @@ def _stager(n_arrays: int, n_words: int, lead: Tuple[int, ...], mesh,
 
     `blocks`: the operands arrive in the mesh's word layout
     (`pim.mesh.WORDS_SPEC`, equal blocks) and each device pads and
-    tiles its own block into its own [waves, chips/mc, banks/mb,
-    subarrays, row_words] slots under `shard_map`: no collective."""
-    from repro.pim.mesh import AXIS_BANKS, AXIS_CHIPS, STAGED_SPEC, WORDS_SPEC
-    if blocks:
-        mc, mb = mesh.shape[AXIS_CHIPS], mesh.shape[AXIS_BANKS]
-        lead = (lead[0], lead[1] // mc, lead[2] // mb, lead[3], lead[4])
-        n_words //= mc * mb
-    pad = lead[0] * lead[1] * lead[2] * lead[3] * lead[4] - n_words
-
-    def impl(arrays: Tuple[jax.Array, ...]) -> jax.Array:
-        return jnp.stack([jnp.pad(jnp.asarray(a, jnp.uint32), (0, pad))
-                          .reshape(lead) for a in arrays], axis=1)
-
+    tiles its own block into its own slots (`_block`) under
+    `shard_map`: no collective."""
+    from repro.pim.mesh import STAGED_SPEC, WORDS_SPEC
     if blocks:
         return jax.jit(jax.shard_map(
-            impl, mesh=mesh, in_specs=((WORDS_SPEC,) * n_arrays,),
+            _stage(*_block(n_words, lead, mesh)), mesh=mesh,
+            in_specs=((WORDS_SPEC,) * n_arrays,),
             out_specs=STAGED_SPEC, check_vma=False))
     shardings = None
     if mesh is not None:
         shardings = NamedSharding(mesh, STAGED_SPEC)
-    return jax.jit(impl, out_shardings=shardings)
+    return jax.jit(_stage(n_words, lead), out_shardings=shardings)
 
 
 def stage_rows(arrays: Sequence[jax.Array], *, geom: DrimGeometry,
@@ -528,12 +570,77 @@ def stage_rows(arrays: Sequence[jax.Array], *, geom: DrimGeometry,
     `tiles` and `waves` are the unsharded plan's either way.  Returns
     (staged, tiles, waves)."""
     n_words = arrays[0].shape[0]
-    row_w = geom.row_bits // WORD_BITS
-    tiles = _ceil_div(n_words, row_w)
-    waves = _ceil_div(tiles, geom.n_subarrays)
-    lead = (waves, geom.chips, geom.banks, geom.subarrays_per_bank, row_w)
+    tiles, waves, lead = tiling(n_words, geom)
     staged = _stager(len(arrays), n_words, lead, mesh, blocks)(tuple(arrays))
+    RUN_STATS["programs"] += 1
     return staged, tiles, waves
+
+
+@functools.lru_cache(maxsize=512)
+def run_program(engine: str, program: Tuple[AAP, ...],
+                result_rows: Tuple[int, ...], n_rows: int,
+                staged: Tuple[Tuple[str, int], ...],
+                outputs: Tuple[Tuple[str, int], ...], n_words: int,
+                lead: Tuple[int, ...], mesh=None, blocks: bool = False,
+                faults=None):
+    """One compiled program for a whole run of a SIMD wave engine
+    ("resident", "baseline", "pallas"): staging, the waves and the
+    readback of `stage_rows` -> `run_waves` -> `read_planes`, composed
+    from the same pieces (`_stage`, `_waves`, `read_planes`).
+
+    It is called as `fn(dev, host)`: `dev` a tuple of device planes,
+    `host` a tuple holding the run's host planes stacked as one
+    [n_host, n_words] block, or empty.  Each plane the program reads is
+    named by a source: ("d", i) device plane i, ("h", j) row j of the
+    host block, ("z", 0) a zero plane made in the program.  `staged`
+    lists the sources of the staged rows in order; each entry of
+    `outputs` is ("col", c), readback column c, or a source, returned
+    as it is (an aliased input).  Returns the output planes as flat
+    words.
+
+    `mesh` (the resident engine over several devices) with `blocks`
+    runs the whole composition under one `shard_map`: each device
+    stages, runs and trims its own block of words (`WORDS_SPEC`) with
+    no collective.  Without `blocks` the planes are whole on every
+    device, the waves run under `shard_map` over the staged payload,
+    and every result is whole on every device.  The jitted function is
+    named `body`, as the wave program's is."""
+    from repro.pim.mesh import STAGED_SPEC, WORDS_SPEC
+    per = n_words
+    if blocks:
+        per, lead = _block(n_words, lead, mesh)
+    stage = _stage(per, lead)
+    waves = _waves(engine, program, result_rows, n_rows, faults)
+    if mesh is not None and not blocks:
+        waves = jax.shard_map(waves, mesh=mesh, in_specs=(STAGED_SPEC,),
+                              out_specs=STAGED_SPEC, check_vma=False)
+
+    def body(dev, host):
+        def plane(src):
+            kind, i = src
+            if kind == "d":
+                return jnp.asarray(dev[i], jnp.uint32).reshape(-1)
+            if kind == "h":
+                return host[0][i]
+            return jnp.zeros(per, jnp.uint32)
+
+        outs = None
+        if any(kind == "col" for kind, _ in outputs):
+            tiles = stage(tuple(plane(s) for s in staged))
+            if mesh is not None and not blocks:
+                tiles = jax.lax.with_sharding_constraint(
+                    tiles, NamedSharding(mesh, STAGED_SPEC))
+            outs = waves(tiles)
+        return tuple(read_planes(outs, (i,), per)[0] if kind == "col"
+                     else plane((kind, i)) for kind, i in outputs)
+
+    if mesh is None:
+        return jax.jit(body)
+    if blocks:
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(WORDS_SPEC, P(None, *WORDS_SPEC)),
+            out_specs=WORDS_SPEC, check_vma=False))
+    return jax.jit(body, out_shardings=NamedSharding(mesh, P()))
 
 
 def dispatch_waves(engine: str, arrays: Sequence[jax.Array],

@@ -422,3 +422,44 @@ def test_forced_4device_layout_subprocess():
         f"forced-4-device layout suite failed:\n{proc.stdout}\n"
         f"{proc.stderr}")
     assert "passed" in proc.stdout and "skipped" not in proc.stdout
+
+
+@pytest.mark.parametrize("i", range(4))
+def test_layout_fused_run_matches_step_by_step(small_geom, i):
+    """A mesh run is one program: words in the layout in, each device
+    staging, running and trimming its own block, results in the layout
+    out.  Bit-exact with the step-by-step path (`stage_rows` ->
+    `run_waves` -> `read_blocks`, or `read_planes` off the blocks
+    layout) with the same schedule; one program, nothing across
+    devices, nothing sent from the host."""
+    import drim
+    import jax.numpy as jnp
+    from repro.pim.compiler import RUN_STATS
+    from repro.pim.mesh import read_blocks, words_layout
+    from repro.pim.scheduler import read_planes, run_waves
+    mesh = fleet_mesh(small_geom)
+    n_words = _layout_words(small_geom, mesh)[i]
+    layout = words_layout(mesh, n_words)
+    a, b = random_operands("xnor2", n_words, seed=40 + i)
+    args = _placed([jnp.asarray(a), jnp.asarray(b)], mesh)
+    low = drim.compile("xnor2", geom=small_geom).lower(mesh=mesh)
+    snap = dict(RUN_STATS)
+    (got,) = low.run(*args)
+    delta = {k: RUN_STATS[k] - snap.get(k, 0) for k in RUN_STATS}
+    staged, tiles, waves = stage_rows(
+        list(args), geom=small_geom,
+        mesh=mesh if layout != "one" else None,
+        blocks=layout == "blocks")
+    outs = run_waves(staged, low.program, low.result_rows,
+                     n_rows=low.n_rows,
+                     mesh=mesh if layout != "one" else None)
+    (want,) = (read_blocks(outs, [0], n_words, mesh) if layout == "blocks"
+               else read_planes(outs, [0], n_words))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got), ~(a ^ b))
+    _check_layout(got, mesh, n_words)
+    assert low.schedule == low.engine.lift_op(low, n_words * 32, tiles,
+                                              waves)
+    assert delta["programs"] == 1 and delta["h2d_transfers"] == 0
+    if layout != "whole":
+        assert delta.get("xchip_bytes", 0) == 0

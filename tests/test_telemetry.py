@@ -213,8 +213,9 @@ def test_span_is_shared_null_when_neither_sink_is_active():
 # Spans on the profiler clock: `drim.*` TraceAnnotations in a jax trace
 # ---------------------------------------------------------------------------
 
-RUN_SPANS = ["drim.run.feeds", "drim.run.stage", "drim.run.dispatch",
-             "drim.run.readback", "drim.run.schedule"]
+# A fused run's phases: the feed checks and the one transfer, the one
+# program (staging, waves and readback), the schedule.
+RUN_SPANS = ["drim.run.feeds", "drim.run.dispatch", "drim.run.schedule"]
 
 
 def _profiled_spans(tmp_path, block):
@@ -245,8 +246,8 @@ def _profiled_spans(tmp_path, block):
 
 def test_run_spans_on_the_profiler_clock(tmp_path, small_geom):
     """A K=4 traced program: `lower` and its passes, then one `run`
-    holding the five phases, all named `drim.*` in the trace, armed or
-    not."""
+    holding the three phases of a fused run, all named `drim.*` in the
+    trace, armed or not."""
     from repro.pim.bnn import bitlinear_kernel
     rng = np.random.default_rng(3)
     planes = [rng.integers(0, 1 << 32, N_WORDS, dtype=np.uint32)
@@ -292,8 +293,9 @@ def test_offload_spans_on_the_profiler_clock(tmp_path, small_geom, m, n,
 
 def test_run_counters_book_host_planes(small_geom):
     """"run.h2d_bytes" counts the uint32 words of host planes only:
-    device operands move nothing, a traced program's host-made constant
-    plane does; "lower.us" counts every lowering."""
+    device operands move nothing, and neither does a traced program's
+    constant plane, made on the device; "lower.us" counts every
+    lowering."""
     from repro.pim.bnn import bitlinear_kernel
     from repro.pim.compiler import LOWER_STATS, RUN_STATS
     rng = np.random.default_rng(5)
@@ -306,13 +308,14 @@ def test_run_counters_book_host_planes(small_geom):
                           geom=small_geom).lower("resident")
         assert LOWER_STATS["us"] > 0
         xnor.run(*dev[:2])
-        assert dict(RUN_STATS) == {"calls": 1}
+        assert dict(RUN_STATS) == {"calls": 1, "programs": 1,
+                                   "h2d_transfers": 0}
         xnor.run(*host[:2])
         assert RUN_STATS["h2d_bytes"] == 2 * N_WORDS * 4
-        k4.run(*dev)                           # the zero plane alone
-        assert RUN_STATS["h2d_bytes"] == 3 * N_WORDS * 4
-        k4.run(*host)                          # 8 planes and the zero plane
-        assert RUN_STATS["h2d_bytes"] == 12 * N_WORDS * 4
+        k4.run(*dev)                           # the zero plane is not sent
+        assert RUN_STATS["h2d_bytes"] == 2 * N_WORDS * 4
+        k4.run(*host)                          # the 8 planes alone
+        assert RUN_STATS["h2d_bytes"] == 10 * N_WORDS * 4
         assert RUN_STATS["calls"] == 4
         snap = telemetry.REGISTRY.snapshot()["counters"]
         assert snap["run.calls"] == 4 and snap["lower.us"] > 0

@@ -93,17 +93,19 @@ def test_flash_attention_compiles_at_prefill_shape(one_chip, direction):
              ((4, 4, 128, 64), jnp.bfloat16))
 
 
-@pytest.mark.parametrize("stage", ["stager", "wave", "readback"])
+@pytest.mark.parametrize("stage", ["stager", "wave", "readback", "run"])
 def test_drim_s4_mesh_path_compiles_without_collectives(topo, stage):
     """The DRIM-S device over the (1, 4) mesh of a described 2x2 host,
     64 banks a chip, 2^29-bit xnor2 operands in the mesh's word layout:
-    the stager, the wave program and the readback compile, and none
-    moves a word between chips."""
+    the stager, the wave program, the readback and the one program of
+    a fused run (`run_program`) compile, and none moves a word between
+    chips."""
     from jax.sharding import NamedSharding
 
     from repro.core import DrimGeometry
     from repro.pim.mesh import STAGED_SPEC, _unstager
-    from repro.pim.scheduler import _stager, _wave_runner, build_program
+    from repro.pim.scheduler import (_stager, _wave_runner, build_program,
+                                     run_program)
     geom = DrimGeometry(banks=256, subarrays_per_bank=152)
     mesh = drim.fleet_mesh(geom, devices=list(topo.devices))
     assert dict(mesh.shape) == {"chips": 1, "banks": 4}
@@ -119,10 +121,17 @@ def test_drim_s4_mesh_path_compiles_without_collectives(topo, stage):
                                  sharding=staged)
         lowered = _wave_runner("resident", tuple(build_program("xnor2")),
                                (2,), 16, mesh, False).lower(x)
-    else:
+    elif stage == "readback":
         x = jax.ShapeDtypeStruct((waves, 1) + lead[1:], jnp.uint32,
                                  sharding=staged)
         lowered = _unstager((0,), n_words // 4, mesh).lower(x)
+    else:
+        w = jax.ShapeDtypeStruct((n_words,), jnp.uint32,
+                                 sharding=drim.words_sharding(mesh))
+        lowered = run_program(
+            "resident", tuple(build_program("xnor2")), (2,), 16,
+            (("d", 0), ("d", 1)), (("col", 0),), n_words, lead, mesh,
+            True).lower((w, w), ())
     text = lowered.compile().as_text()
     assert not [c for c in ("all-gather", "all-to-all", "collective-permute",
                             "all-reduce", "reduce-scatter") if c in text]
